@@ -1,0 +1,362 @@
+"""Self-checks of the benchmark, on the CPU at sizes a test run holds.
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        python3 -m pytest -q -p no:cacheprovider bench/test_bench.py
+
+* the benchmark's generator gives the program's arrays;
+* the trace reduction's arithmetic, on a trace written by hand, and the
+  in-memory recorder's cut at its cap;
+* each kernel's bytes-per-call function against the shapes the engine
+  passes to the kernel (Pallas interpreter);
+* the reference equals the program on every protocol, and the control
+  (the reference with isolation switched off) does not;
+* the harness run end to end at a small size is correct, and each fault
+  planted under it (a runner that returns its state unchanged, half of a
+  cell's transaction stream left out, a counter altered where it is
+  produced, a compile inside the window) makes it come out not correct.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from bench import gen, run, trace  # noqa: E402
+from bench.reference import core as ref  # noqa: E402
+
+SMALL_BUDGET = dict(max_rounds=600, warmup_rounds=200, chunk_rounds=200,
+                    target_commits=100_000_000)
+
+
+def small_cell(name: str, txns: int = 512, pool: int = 2) -> run.Cell:
+    """The cell as BENCHMARK.json defines it, cut to a test's size."""
+    cell = run.load_cell(name)
+    cell.config = json.loads(json.dumps(cell.config))
+    cell.traffic = json.loads(json.dumps(cell.traffic))
+    cell.config["workload"]["num_txns"] = txns
+    cell.config[cell.traffic["budget"]] = dict(SMALL_BUDGET)
+    cell.traffic["pool_calls"] = pool
+    return cell
+
+
+# ------------------------------------------------------------- generator
+GEN_CASES = [
+    dict(kind="ycsb", num_txns=2048, num_hot=64, seed=0),
+    dict(kind="ycsb", num_txns=2048, num_hot=16, seed=2**31 + 11),
+    dict(kind="ycsb", num_txns=1024, num_hot=1024, hot_per_txn=2, seed=5),
+    dict(kind="ycsb", num_txns=1024, num_hot=0, partitions_per_txn=2,
+         seed=6),
+    dict(kind="ycsb", num_txns=1024, num_hot=16, multipart_frac=1.0,
+         seed=7),
+    dict(kind="tpcc", num_txns=2048, num_warehouses=4, seed=0),
+    dict(kind="tpcc", num_txns=2048, num_warehouses=16, seed=3_000_000_001),
+]
+
+
+@pytest.mark.parametrize("kw", GEN_CASES, ids=lambda kw: f"{kw['kind']}"
+                         f"-{kw.get('num_hot', kw.get('num_warehouses'))}"
+                         f"-{kw['seed']}")
+def test_generator_matches_program(kw):
+    from repro.core.workloads import WorkloadConfig, make_workload
+
+    want = make_workload(WorkloadConfig(**kw))
+    got = gen.make_workload(gen.GenConfig(**kw))
+    for k in ("keys", "modes", "nkeys", "part", "exec_ops", "ollp",
+              "ollp_miss"):
+        a, b = getattr(got, k), getattr(want, k)
+        assert a.dtype == b.dtype and np.array_equal(a, b), k
+    assert got.num_records == want.num_records
+
+
+# ------------------------------------------------------- trace reduction
+def _ev(name, start, dur):
+    return trace.Event(name, start, dur)
+
+
+def hand_trace() -> trace.Trace:
+    """Two chips, a window [100, 1100) ns, host spans and Python frames."""
+    dev0 = {
+        "XLA Ops": [_ev("fusion.1", 50, 100),  # clipped to [100, 150)
+                    _ev("fusion.2", 120, 80),  # overlaps: union to 200
+                    _ev("%dep_wavefront.11 = (s32[8,128]) custom-call("
+                        "%a, %b)", 400, 100),
+                    # takes the kernel's output as an operand: not the kernel
+                    _ev("%fusion.4 = s32[8,128] fusion(%dep_wavefront.11)",
+                        500, 0),
+                    _ev("fusion.1", 900, 300)],  # clipped to 1100
+        "XLA Modules": [_ev("jit_run_chunk(1)", 100, 100),
+                        _ev("jit_run_chunk(1)", 400, 100),
+                        _ev("jit_other", 900, 200)],
+    }
+    dev1 = {"XLA Ops": [_ev("fusion.3", 300, 200)],
+            "XLA Modules": [_ev("jit_run_chunk(2)", 300, 200)]}
+    host = {"python3": [
+        _ev("bench.window", 100, 1000),
+        _ev("bench.call", 100, 500),
+        _ev("$engine.py:2827 make_plan", 200, 150),
+        _ev("$planner.py:146 plan_dgcc", 210, 100),  # nested: not counted
+        _ev("$engine.py:2827 make_plan", 620, 50),
+        _ev("$other.py:1 make_plan", 700, 999),  # another file
+        _ev("bench.call", 600, 400),
+    ]}
+    return trace.Trace(devices={"/device:TPU:0": dev0, "/device:TPU:1": dev1},
+                       host=host, lo=100, hi=1100)
+
+
+def test_trace_reduction_by_hand():
+    tr = hand_trace()
+    # chip 0: [100, 200) + [400, 500) + [900, 1100) = 400 ns busy
+    assert trace.busy_ns(tr, "/device:TPU:0") == 400
+    assert trace.busy_ns(tr, "/device:TPU:1") == 200
+    plans = trace.frames(tr, "make_plan", "engine.py")
+    assert [e.dur for e in plans] == [150, 50]
+    # the other file's frame ends past the window
+    assert [e.dur for e in trace.frames(tr, "make_plan")] == [150, 50]
+    by = trace.sum_by_name(trace.in_window(tr, tr.ops("/device:TPU:0")),
+                           short=True)
+    assert by == {"fusion.2": 80, "dep_wavefront.11": 100, "fusion.1": 300,
+                  "fusion.4": 0}
+    # gaps of chip 0: [200, 400) and [500, 900), longest first
+    assert trace.gaps(tr.ops("/device:TPU:0"), tr.lo, tr.hi) == [
+        (500, 900), (200, 400)]
+    # the gap [500, 900) is covered by the second call span for 300 of
+    # its 400 ns, more than by any other host span
+    assert trace.idle_gaps(tr, "/device:TPU:0")[0] == ["bench.call", 4e-7]
+    assert trace.union_ns([_ev("a", 0, 10), _ev("b", 5, 10),
+                           _ev("c", 30, 5)], 0, 100) == 20
+
+
+def test_recorder_cuts_the_trace_at_its_cap():
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = np.ones((128, 128), np.float32)
+    f(x).block_until_ready()
+    rec = trace.Recorder(cap_s=0.2)
+    t0 = time.perf_counter()
+    while not rec.stopped:
+        with jax.profiler.TraceAnnotation("bench.call"):
+            f(x).block_until_ready()
+    tr = rec.finish()
+    took = time.perf_counter() - t0
+    assert 0.15e9 < tr.hi - tr.lo <= took * 1e9
+    names = {e.name for evs in tr.host.values() for e in evs}
+    assert {"bench.mark", "bench.call"} <= names
+
+
+def test_metric_readers_by_hand():
+    from bench.metrics import (device_idle, dep_wavefront_roofline, plan_s,
+                               step_us)
+
+    tr = hand_trace()
+    cell = small_cell("ycsb10m_40c.dgcc_hot64")
+    meta = type("M", (), {"pred_width": 3, "max_keys": 10})()
+    c = run.SimCell(engine={}, stream=0, protocol="dgcc", slots=128,
+                    meta=meta)
+    res = type("R", (), {"raw": {"steps_executed": 4, "group_cells": 1}})()
+    call = run.Call(seeds=[0], streams=[None], cells=[c], start_ns=100,
+                    end_ns=1100, results=[res])
+    ctx = run.TraceContext(tr, cell, [call], [0, 1],
+                           run.peaks_of("TPU v5 lite"))
+    # chip 0 idle 600/1000, chip 1 idle 800/1000
+    assert device_idle.read(ctx) == pytest.approx(70.0)
+    # iterations are counted for a call on one chip only
+    assert step_us.read(ctx) is None
+    ctx = run.TraceContext(tr, cell, [call], [0],
+                           run.peaks_of("TPU v5 lite"))
+    # chip 0's runner time 100 + 100 ns over 4 iterations
+    assert step_us.read(ctx) == pytest.approx(0.05)
+    # two make_plan frames of engine.py, 150 and 50 ns
+    assert plan_s.read(ctx) == pytest.approx(100e-9)
+    # one kernel event of 100 ns; the fusion that reads its output is not
+    want = (dep_wavefront_roofline.bytes_per_call(128, 3) / 819e9
+            / 100e-9 * 100)
+    assert dep_wavefront_roofline.read(ctx) == pytest.approx(want)
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(SystemExit, match="no peaks"):
+        run.peaks_of("TPU v99")
+    assert run.peaks_of("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+
+
+# ------------------------------------------------------ kernel shapes
+def _record_kernel(monkeypatch, module, attr):
+    """Wrap a kernel entry so every trace of it records its arrays."""
+    import importlib
+
+    mod = importlib.import_module(module)
+    real = getattr(mod, attr)
+    seen = []
+
+    def wrapped(*args, **kw):
+        out = real(*args, **kw)
+        # compiled for the TPU, every tile (bool ones too) is s32
+        arrays = list(args) + list(out)
+        seen.append(sum(int(np.prod(a.shape)) * 4 for a in arrays))
+        return out
+
+    monkeypatch.setattr(mod, attr, wrapped)
+    return seen
+
+
+@pytest.mark.parametrize("name,module,attr", [
+    ("ycsb10m_40c.dgcc_hot64", "repro.kernels.dep_wavefront.ops",
+     "dep_wavefront_kernel"),
+    ("tpcc_w4_80c.orthrus", "repro.kernels.lock_grant.ops",
+     "lock_grant_kernel"),
+])
+def test_kernel_bytes_match_engine_shapes(monkeypatch, name, module, attr):
+    import importlib
+
+    from repro.core import engine as engine_lib
+    from repro.core import sweep
+
+    metric = {"dep_wavefront_kernel": "dep_wavefront_roofline",
+              "lock_grant_kernel": "lock_grant_roofline"}[attr]
+    reader = importlib.import_module(f"bench.metrics.{metric}")
+    cell = small_cell(name, txns=256)
+    call = run.build_pool(cell, 3)[0]
+    run.plan_shapes(call, cell)
+    seen = _record_kernel(monkeypatch, module, attr)
+    cfg, wl = run.call_args(call, cell, dict(max_rounds=8, warmup_rounds=0,
+                                             chunk_rounds=8))[0]
+    cfg = dataclasses.replace(cfg, kernel_impl="pallas")
+    plan = engine_lib.make_plan(cfg, wl)
+    sweep.simulate_plans(cfg, [plan], mode=sweep.SERIAL_MODE)
+    assert seen, "the engine never called the kernel"
+    assert set(seen) == {reader.call_bytes(call.cells[0])}
+
+
+# ---------------------------------------------- reference and control
+REF_CASES = [
+    (dict(kind="ycsb", num_hot=64), dict(protocol="twopl_waitdie", n_exec=8)),
+    (dict(kind="ycsb", num_hot=16), dict(protocol="twopl_waitfor", n_exec=8)),
+    (dict(kind="ycsb", num_hot=16),
+     dict(protocol="twopl_dreadlocks", n_exec=8)),
+    (dict(kind="ycsb", num_hot=64), dict(protocol="deadlock_free", n_exec=8)),
+    (dict(kind="ycsb", num_hot=64),
+     dict(protocol="partitioned_store", n_exec=8)),
+    (dict(kind="ycsb", num_hot=64),
+     dict(protocol="dgcc", n_cc=2, n_exec=8, window=4)),
+    (dict(kind="ycsb", num_hot=64),
+     dict(protocol="quecc", n_cc=2, n_exec=8, window=4)),
+    (dict(kind="tpcc", num_warehouses=4),
+     dict(protocol="orthrus", n_cc=4, n_exec=16, window=4)),
+]
+
+
+@pytest.mark.parametrize("wkw,ekw", REF_CASES,
+                         ids=[e["protocol"] for _, e in REF_CASES])
+def test_reference_matches_program_and_control_does_not(wkw, ekw):
+    from repro.core import sweep
+    from repro.core.engine import EngineConfig
+    from repro.core.workloads import WorkloadConfig, make_workload
+
+    kw = dict(wkw, num_txns=1024, seed=2**31 + 9)
+    w = gen.make_workload(gen.GenConfig(**kw))
+    res = sweep.run_cells([(EngineConfig(**ekw, **SMALL_BUDGET),
+                            make_workload(WorkloadConfig(**kw)))])[0]
+    want = ref.simulate(ref.RefConfig(**ekw, **SMALL_BUDGET), w)
+    got = run.fingerprint(res)
+    assert run.mismatches(got, want) == []
+    control = ref.simulate(
+        ref.RefConfig(**ekw, **SMALL_BUDGET, isolation=False), w)
+    assert run.mismatches(control, want) != []
+
+
+# ------------------------------------------- the harness, end to end
+def _run(cell, seed=2**31 + 3, seconds=0.5):
+    counter = run.CompileCounter()
+    devices = jax.devices()[:cell.chips]
+    return run.run(cell, seed, seconds, False, devices, counter)
+
+
+def test_harness_small_cell_is_correct():
+    cell = small_cell("ycsb10m_40c.waitdie_hot64")
+    out = _run(cell)
+    assert out["correct"] and out["attempted"] >= 1
+    assert out["window_compiles"] == 0
+    assert list(out["metrics"]) == ["cell_s", "setup_s"]
+    assert list(out)[-1] == "checks"
+
+
+def _fault_runner_unchanged(monkeypatch):
+    from repro.core import sweep
+
+    ident = jax.jit(lambda p, s, r_end: s)
+    monkeypatch.setattr(sweep, "get_runner", lambda *a, **k: ident)
+
+
+def _fault_half_left_out(monkeypatch):
+    from repro.core import engine as engine_lib
+
+    real = engine_lib.make_plan
+
+    def first_half(cfg, wl):
+        n = len(wl.nkeys) // 2
+        return real(cfg, dataclasses.replace(wl, **{
+            k: getattr(wl, k)[:n] for k in ("keys", "modes", "nkeys", "part",
+                                            "exec_ops", "ollp",
+                                            "ollp_miss")}))
+
+    monkeypatch.setattr(engine_lib, "make_plan", first_half)
+
+
+def _fault_counter_altered(monkeypatch):
+    from repro.core import sweep
+
+    real = sweep._GroupRun.finish
+
+    def altered(self, time_sink=None):
+        out = real(self, time_sink)
+        for res in out:
+            res.raw["steps_executed"] += 1
+        return out
+
+    monkeypatch.setattr(sweep._GroupRun, "finish", altered)
+
+
+FAULTS = {
+    "runner_unchanged": ("ycsb10m_40c.dgcc_hot64", _fault_runner_unchanged),
+    "half_left_out": ("ycsb10m_40c.waitdie_hot64", _fault_half_left_out),
+    "counter_altered": ("tpcc_w4_80c.orthrus", _fault_counter_altered),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_planted_fault_is_not_correct(monkeypatch, fault):
+    name, plant = FAULTS[fault]
+    cell = small_cell(name, txns=256, pool=1)
+    if cell.chips > len(jax.devices()):
+        pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_device_"
+                    f"count={cell.chips}")
+    plant(monkeypatch)
+    out = _run(cell, seconds=0.01)
+    assert not out["correct"]
+    assert out["checks"]["mismatches"]["value"] > 0
+
+
+def test_compile_inside_the_window_is_not_correct(monkeypatch):
+    from repro.core import sweep
+
+    cell = small_cell("ycsb10m_40c.waitdie_hot64", txns=256, pool=1)
+    real = sweep.get_runner
+    # a runner built afresh for every group the window runs, as one for a
+    # shape that set-up did not warm would be
+    def fresh(*a, **k):
+        runner = real(*a, **k)
+        return jax.jit(lambda *x: runner(*x))
+
+    monkeypatch.setattr(sweep, "get_runner", fresh)
+    out = _run(cell, seconds=0.01)
+    assert out["checks"]["window_compiles"]["value"] > 0
+    assert not out["correct"]
