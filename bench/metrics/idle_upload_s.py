@@ -1,0 +1,10 @@
+"""idle_upload_s (s): chip idle time per simulation cell, averaged over
+the cell's chips, inside the completed calls' ``bench.call`` spans while
+the innermost ``repro.*`` host span open is ``repro.upload`` (plan
+arrays, group state, stacking and ``device_put``) (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.idle_s(ctx, "upload")

@@ -1,0 +1,8 @@
+"""stage_acquire_us (us): device time per loop iteration of the round
+step's ``stage.acquire`` scope (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.stage_us(ctx, "acquire")

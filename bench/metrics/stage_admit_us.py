@@ -1,0 +1,8 @@
+"""stage_admit_us (us): device time per loop iteration of the round
+step's ``stage.admit`` scope (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.stage_us(ctx, "admit")

@@ -1,0 +1,8 @@
+"""stage_commit_us (us): device time per loop iteration of the round
+step's ``stage.commit`` scope (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.stage_us(ctx, "commit")

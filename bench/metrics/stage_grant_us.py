@@ -1,0 +1,8 @@
+"""stage_grant_us (us): device time per loop iteration of the round
+step's ``stage.grant`` scope (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.stage_us(ctx, "grant")

@@ -1,0 +1,8 @@
+"""stage_rollover_us (us): device time per loop iteration of the round
+step's ``stage.rollover`` scope (``bench/scopes.py``)."""
+
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.stage_us(ctx, "rollover")

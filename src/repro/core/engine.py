@@ -1088,933 +1088,945 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
                 )
 
         # --------------------------------------- 1a. admission-control drops
-        # Queue-side policy drops advance next_txn *before* slot ranking,
-        # so dropped txns are never loaded and cost nothing downstream.
-        # Drops happen only at executed rounds; the stage-12 leap
-        # candidates guarantee none falls strictly inside a leap gap, so
-        # the counters are bit-identical dense vs leaped.
-        if policy == "bounded_backlog":
-            # drop the oldest waiters beyond the backlog cap
-            drop = jnp.maximum(
-                arrived_by(r) - p["pol_cap"] - s["next_txn"], 0
-            )
-            s["pol_rejected"] = s["pol_rejected"] + drop
-            s["next_txn"] = s["next_txn"] + drop
-        elif policy == "deadline_shed":
-            # drop waiters whose queueing delay exceeds the deadline:
-            # txns arrived by r - deadline - 1 have waited > deadline
-            drop = jnp.maximum(
-                arrived_by(r - p["pol_deadline"] - 1) - s["next_txn"], 0
-            )
-            s["pol_shed"] = s["pol_shed"] + drop
-            s["next_txn"] = s["next_txn"] + drop
-
-        # ------------------------------------------ 1+2. admission & retry
-        # New admissions (EMPTY slots) and backoff->retry (BACKOFF slots
-        # whose timer expired) are disjoint and share most column resets,
-        # so they run as one fused masked update.
-        empty = phase == EMPTY
-        if lane_stream is None:
-            rank = jnp.cumsum(empty.astype(i32)) - 1
-            new_tid = s["next_txn"] + rank
-            if open_arrival:
-                # global txn id g arrives with its epoch; arrival is
-                # monotone in g, so the admitted set is a prefix of the
-                # ranked empty slots and tids stay contiguous
-                arr_t = arr_of(new_tid)
-                adm = empty & (arr_t <= r)
-                if policy == "token_bucket":
-                    # backpressure, no drops: txn g additionally waits
-                    # for token g — the bucket starts with token_burst
-                    # and refills one every token_interval_rounds
-                    # (cost_model.token_grant is the host oracle). The
-                    # gate loosens as g falls, so the admitted set is
-                    # still a prefix of the ranked empty slots.
-                    adm = adm & (
-                        new_tid
-                        < p["pol_tb_burst"] + r // p["pol_tb_iv"]
-                    )
-                    s["pol_tb_adm"] = s["pol_tb_adm"] + adm.sum(
-                        dtype=i32
-                    )
-            else:
-                adm = empty
-            new_widx = new_tid % N
-            s["next_txn"] = s["next_txn"] + adm.sum(dtype=i32)
-        else:
-            # H-Store routing: each worker lane pulls the next txn homed to
-            # its partition (lanes with no homed txns stay idle).
-            M = meta.lane_cols
-            new_widx = lane_stream[slot_ids, lane_ctr % M]
-            adm = empty & (new_widx >= 0)
-            new_tid = lane_ctr * T + slot_ids
-            lane_ctr = jnp.where(adm, lane_ctr + 1, lane_ctr)
-            s["next_txn"] = s["next_txn"] + adm.sum(dtype=i32)
-        retry = (phase == BACKOFF) & free
-        reset = adm | retry
-        widx = jnp.where(adm, new_widx, widx)
-        tid = jnp.where(adm, new_tid, tid)
-        ts = jnp.where(adm, new_tid, ts)
-        # metrics: stamp the txn's arrival round — its epoch arrival
-        # under open arrival (latency then includes queueing delay), the
-        # admission round under closed loop. Retries keep the stamp, so
-        # latency spans aborts end-to-end.
-        arrive = jnp.where(adm, arr_t if open_arrival else r, arrive)
-        attempt = jnp.where(adm, 0, jnp.where(retry, attempt + 1, attempt))
-        # per-slot workload columns for the loaded txns (the scalar
-        # per-txn fields ride one fused [N, 4] gather)
-        wsafe = jnp.where(tid >= 0, widx % N, 0)
-        keys = wkeys[wsafe]
-        modes = wmodes[wsafe]
-        ccids = wpart[wsafe] % n_cc
-        sc = sc_all[wsafe]
-        nkeys = sc[:, 0]
-        execops = sc[:, 1]
-        ollp = sc[:, 2] != 0
-        miss = sc[:, 3] != 0
-        kvalid = kk[None, :] < nkeys[:, None]
-        init_busy = rounds_of(
-            cm.txn_fixed_cycles
-            + jnp.where(ollp, cm.recon_cycles, 0)
-        )
-        phase = jnp.where(reset, INIT, phase)
-        busy_until = jnp.where(
-            adm,
-            r + init_busy,
-            jnp.where(retry, r + rounds_of(cm.txn_fixed_cycles), busy_until),
-        )
-        busy_kind = jnp.where(reset, CAT_LOCK, busy_kind)
-        for f in ("want", "granted", "adm_done", "rel_done"):
-            s[f] = jnp.where(reset[:, None], False, s[f])
-        kptr = jnp.where(reset, 0, kptr)
-        ccptr = jnp.where(reset, 0, ccptr)
-        waited = jnp.where(reset, False, waited)
-
-        free = busy_until <= r
-
-        # ------------------------------------------------ 3. INIT -> acquire
-        start = (phase == INIT) & free & (tid >= 0)
-        if cfg.is_orthrus:
-            phase = jnp.where(start, MSG, phase)
-            msg_stage = jnp.where(start, 0, msg_stage)
-            msg_arrive = jnp.where(start, r + cm.msg_hop_rounds, msg_arrive)
-        else:
-            phase = jnp.where(start, ACQ, phase)
-
-        # ------------------------------------------------ 4. ORTHRUS CC work
-        if cfg.is_orthrus:
-            # -- admission of acquire-messages and release-messages, bounded
-            #    by each CC lane's per-round key-op capacity, in ts order.
-            in_cur_group = (
-                (kk[None, :] >= ccptr[:, None])
-                & kvalid
-                & (ccids == jnp.take_along_axis(
-                    ccids, jnp.minimum(ccptr, K - 1)[:, None], axis=1))
-            )
-            acq_cand = (
-                (phase == MSG) & (msg_stage == 0) & (msg_arrive <= r)
-            )
-            acq_keys = acq_cand[:, None] & in_cur_group & ~s["adm_done"]
-            rel_cand = (phase == REL) & (release_at <= r)
-            rel_keys = rel_cand[:, None] & s["granted"] & ~s["rel_done"]
-            # Rank every active entry within its CC lane by (ts, key slot)
-            # — the admission order — without sorting all T*K entries: a
-            # slot's entries share its (unique) ts, so a [T] slot sort plus
-            # per-CC prefix counts reproduces the (cc, ts, entry) rank
-            # exactly at a fraction of the cost.
-            act2d = acq_keys | rel_keys  # [T, K]
-            cc_act = jnp.where(act2d, ccids, n_cc)
-            cnt_tc = (
-                jnp.zeros((T, n_cc + 1), jnp.int32)
-                .at[jnp.broadcast_to(slot_ids[:, None], (T, K)), cc_act]
-                .add(1)
-            )
-            slot_order = jnp.argsort(ts, stable=True)  # ts unique
-            cnt_sorted = cnt_tc[slot_order]
-            excl_sorted = jnp.cumsum(cnt_sorted, axis=0) - cnt_sorted
-            excl = jnp.zeros_like(excl_sorted).at[slot_order].set(excl_sorted)
-            base_rank = jnp.take_along_axis(excl, cc_act, axis=1)
-            same_cc_earlier = (
-                (cc_act[:, :, None] == cc_act[:, None, :])
-                & act2d[:, None, :]
-                & (kk[None, None, :] < kk[None, :, None])
-            )
-            within = same_cc_earlier.sum(-1, dtype=jnp.int32)
-            seg_pos2d = base_rank + within + 1  # 1-based within CC lane
-            proc2d = (seg_pos2d <= cap_keys) & act2d
-            s["adm_done"] = s["adm_done"] | (proc2d & acq_keys.reshape(T, K))
-            # group fully admitted -> requests live in the CC's lock table
-            grp_all = jnp.where(in_cur_group, s["adm_done"], True).all(axis=1)
-            admit_now = acq_cand & grp_all
-            new_want = admit_now[:, None] & in_cur_group
-            phase = jnp.where(admit_now, ACQ, phase)
-            # release processing
-            do_rel = proc2d & rel_keys.reshape(T, K)
-            rel_k = jnp.where(do_rel, keys, 0)
-            is_wr = do_rel & (modes == MODE_WRITE)
-            s["wh"] = s["wh"].at[jnp.where(is_wr, rel_k, R)].set(
-                -1, mode="drop"
-            )
-            is_rd = do_rel & (modes == MODE_READ)
-            s["rc"] = s["rc"].at[jnp.where(is_rd, rel_k, R)].add(
-                -1, mode="drop"
-            )
-            s["rel_done"] = s["rel_done"] | do_rel
-            s["granted"] = s["granted"] & ~do_rel
-        else:
-            new_want = jnp.zeros((T, K), jnp.bool_)
-
-        # ------------------------------------------------ 5. shared releases
-        rel_entries = jnp.zeros((T, K), jnp.bool_)
-        if not cfg.is_orthrus:
-            rel_now = (phase == REL) & (release_at <= r)
-            rel_entries = rel_now[:, None] & s["granted"]
-            rel_k = jnp.where(rel_entries, keys, 0)
-            is_wr = rel_entries & (modes == MODE_WRITE)
-            s["wh"] = s["wh"].at[jnp.where(is_wr, rel_k, R)].set(
-                -1, mode="drop"
-            )
-            is_rd = rel_entries & (modes == MODE_READ)
-            s["rc"] = s["rc"].at[jnp.where(is_rd, rel_k, R)].add(
-                -1, mode="drop"
-            )
-            if need_rdr:
-                # clear the slot's reader bit once per *distinct*
-                # released read key: a slot releases all its granted
-                # entries at once, and re-entrant reads may hold several
-                # columns on one record — only the first contributes
-                dup = (
-                    (keys[:, :, None] == keys[:, None, :])
-                    & is_rd[:, None, :]
-                    & (kk[None, None, :] < kk[None, :, None])
-                ).any(-1)
-                first_rd = is_rd & ~dup
-                s["rdr"] = s["rdr"].at[
-                    jnp.where(first_rd, rel_k, R),
-                    jnp.broadcast_to(rdr_word[:, None], (T, K)),
-                ].add(
-                    jnp.where(first_rd, -rdr_bit[:, None], 0), mode="drop"
+        with jax.named_scope("stage.admit"):
+            # Queue-side policy drops advance next_txn *before* slot ranking,
+            # so dropped txns are never loaded and cost nothing downstream.
+            # Drops happen only at executed rounds; the stage-12 leap
+            # candidates guarantee none falls strictly inside a leap gap, so
+            # the counters are bit-identical dense vs leaped.
+            if policy == "bounded_backlog":
+                # drop the oldest waiters beyond the backlog cap
+                drop = jnp.maximum(
+                    arrived_by(r) - p["pol_cap"] - s["next_txn"], 0
                 )
-            s["granted"] = s["granted"] & ~rel_entries
+                s["pol_rejected"] = s["pol_rejected"] + drop
+                s["next_txn"] = s["next_txn"] + drop
+            elif policy == "deadline_shed":
+                # drop waiters whose queueing delay exceeds the deadline:
+                # txns arrived by r - deadline - 1 have waited > deadline
+                drop = jnp.maximum(
+                    arrived_by(r - p["pol_deadline"] - 1) - s["next_txn"], 0
+                )
+                s["pol_shed"] = s["pol_shed"] + drop
+                s["next_txn"] = s["next_txn"] + drop
 
-        # ------------------------------------------------ 6. requests: want
-        if cfg.is_orthrus:
-            s["want"] = s["want"] | new_want
-            want_new = new_want
-        else:
-            # 2PL/DF/pstore: single in-flight request at kptr when ACQ & free
-            at_k = kk[None, :] == kptr[:, None]
-            need = (
-                ((phase == ACQ) & free)[:, None]
-                & at_k
-                & kvalid
-                & ~s["granted"]
-                & ~s["want"]
-            )
-            want_new = need
-            s["want"] = s["want"] | need
-
-        # assign enqueue order stamps to new queue entries
-        if cfg.is_orthrus:
-            flat_new = want_new.reshape(-1)
-            new_rank = jnp.cumsum(flat_new.astype(jnp.int32)) - 1
-            enq_val = (s["enq_ctr"] + new_rank).reshape(T, K)
-            s["enq"] = jnp.where(want_new, enq_val, s["enq"])
-            n_new = flat_new.sum(dtype=jnp.int32)
-        else:
-            # <= 1 new request per slot: rank over [T], same stamps as the
-            # row-major flat cumsum (one entry per row)
-            new_t = want_new.any(axis=1)
-            new_rank = jnp.cumsum(new_t.astype(jnp.int32)) - 1
-            s["enq"] = jnp.where(
-                want_new, (s["enq_ctr"] + new_rank)[:, None], s["enq"]
-            )
-            n_new = new_t.sum(dtype=jnp.int32)
-        # releases consume stamp ids too (bit-compatible with the sorted
-        # grant pass, where they participate as REQ_RELEASE entries)
-        s["enq_ctr"] = s["enq_ctr"] + n_new + rel_entries.sum(dtype=jnp.int32)
-
-        # ------------------------------------------------ 7. grant pass
-        # Requests are live only while their slot is acquiring.
-        pend2d = s["want"] & ~s["granted"] & (phase == ACQ)[:, None]
-        newop2d = want_new | rel_entries  # fresh lock-table ops this round
-        if cfg.is_orthrus:
-            ent_kind = jnp.where(
-                pend2d,
-                jnp.where(modes == MODE_WRITE, REQ_WRITE, REQ_READ),
-                jnp.where(rel_entries, REQ_RELEASE, REQ_NONE),
-            ).reshape(-1)
-            ent_key = jnp.where(
-                (pend2d | rel_entries), keys, KEY_SENTINEL
-            ).reshape(-1)
-            ent_enq = s["enq"].reshape(-1)
-            safe = jnp.minimum(ent_key, R - 1)
-            in_rng = ent_key < R
-            if use_pallas:
-                # the Pallas segmented-grant kernel (compiled on
-                # TPU/GPU, interpreted elsewhere — see
-                # kernels.resolve_interpret); bit-identical to the jnp
-                # path below, which is its oracle
-                g_flat, _cont = _lock_grant(
-                    ent_key, ent_enq, ent_kind, s["wh"], s["rc"],
-                    num_records=R, block_n=grant_block,
-                )
-                grant = g_flat.reshape(T, K)
-            else:
-                wh_free = (s["wh"][safe] == -1) & in_rng
-                rcv = jnp.where(in_rng, s["rc"][safe], 0)
-                order = lex_order(ent_key, ent_enq)
-                inv = inverse_permutation(order)
-                g_sorted, _cont, _new = segmented_grant(
-                    ent_key[order],
-                    ent_enq[order],
-                    ent_kind[order],
-                    wh_free[order],
-                    rcv[order],
-                )
-                grant = g_sorted[inv].reshape(T, K)
-            # re-entrant grants bypass the FIFO: a slot re-requesting a key
-            # it already write-holds is granted immediately (real
-            # transactions touch the same row more than once; without this
-            # they would deadlock on their own lock)
-            ent_slot = jnp.broadcast_to(slot_ids[:, None], (T, K)).reshape(-1)
-            self_grant = (
-                (ent_kind != REQ_NONE)
-                & (ent_kind != REQ_RELEASE)
-                & in_rng
-                & (s["wh"][safe] == ent_slot)
-            )
-            grant = grant | self_grant.reshape(T, K)
-
-            # apply grants to the lock table
-            gk = jnp.where(grant, keys, 0)
-            g_wr = grant & (modes == MODE_WRITE)
-            g_rd = grant & (modes == MODE_READ)
-            holder = jnp.broadcast_to(slot_ids[:, None], (T, K))
-            s["wh"] = s["wh"].at[jnp.where(g_wr, gk, R)].set(
-                holder, mode="drop"
-            )
-            s["rc"] = s["rc"].at[jnp.where(g_rd, gk, R)].add(1, mode="drop")
-        else:
-            # single pending request per slot, at column kptr: FIFO
-            # decisions among the <= T compact requests via an all-pairs
-            # [T, T] key comparison — no sort, no scatter
-            kptr_c = jnp.minimum(kptr, K - 1)[:, None]
-            pend_t = jnp.take_along_axis(pend2d, kptr_c, axis=1).squeeze(1)
-            rkey = jnp.take_along_axis(keys, kptr_c, axis=1).squeeze(1)
-            renq = jnp.take_along_axis(s["enq"], kptr_c, axis=1).squeeze(1)
-            rmode = jnp.take_along_axis(modes, kptr_c, axis=1).squeeze(1)
-            is_wr_req = pend_t & (rmode == MODE_WRITE)
-            if use_csr:
-                # compact CSR grant: sort the <= T pending requests by
-                # (key, stamp), take segmented minima of the enqueue
-                # stamps, and scatter them back to slot order — work
-                # sized to the request list (O(T log T)) instead of the
-                # all-pairs [T, T] stamp comparison below. Non-pending
-                # slots sort to a sentinel segment whose minima are
-                # never read (grant_t is masked by pend_t).
-                skey = jnp.where(pend_t, rkey, _IMAX)
-                order = lex_order(skey, renq)
-                ks = skey[order]
-                eqs = renq[order]
-                seg_start = jnp.concatenate(
-                    [jnp.ones((1,), jnp.bool_), ks[1:] != ks[:-1]]
-                )
-                seg_id = jnp.cumsum(seg_start.astype(i32)) - 1
-                min_req_seg = jax.ops.segment_min(
-                    eqs, seg_id, num_segments=T
-                )
-                min_wr_seg = jax.ops.segment_min(
-                    jnp.where(is_wr_req[order], eqs, _IMAX), seg_id,
-                    num_segments=T,
-                )
-                min_req = (
-                    jnp.zeros((T,), i32).at[order].set(min_req_seg[seg_id])
-                )
-                min_wr = (
-                    jnp.zeros((T,), i32).at[order].set(min_wr_seg[seg_id])
-                )
-            else:
-                same_key = (rkey[None, :] == rkey[:, None]) & pend_t[None, :]
-                enq_b = jnp.broadcast_to(renq[None, :], (T, T))
-                min_wr = jnp.min(
-                    jnp.where(same_key & is_wr_req[None, :], enq_b, _IMAX),
-                    axis=1,
-                )
-                min_req = jnp.min(jnp.where(same_key, enq_b, _IMAX), axis=1)
-            rkey_c = jnp.minimum(rkey, R - 1)
-            whv = s["wh"][rkey_c]
-            rc_t = s["rc"][rkey_c]
-            wh_free_t = whv == -1
-            # read grant: write-free record, no older write request queued;
-            # write grant: write-free, zero read holders, oldest request.
-            # enq stamps are unique, so strict compares are exact.
-            grant_rd = wh_free_t & (min_wr > renq)
-            grant_wr = wh_free_t & (rc_t == 0) & (min_req == renq)
-            grant_t = pend_t & jnp.where(
-                rmode == MODE_WRITE, grant_wr, grant_rd
-            )
-            # re-entrant grants bypass the FIFO (see the ORTHRUS path)
-            grant_t = grant_t | (pend_t & (whv == slot_ids))
-            grant = pend2d & grant_t[:, None]
-
-            # apply grants to the lock table ([T]-sized scatters: only the
-            # kptr column can be granted)
-            g_wr_t = grant_t & (rmode == MODE_WRITE)
-            g_rd_t = grant_t & (rmode == MODE_READ)
-            s["wh"] = s["wh"].at[jnp.where(g_wr_t, rkey, R)].set(
-                slot_ids, mode="drop"
-            )
-            s["rc"] = s["rc"].at[jnp.where(g_rd_t, rkey, R)].add(
-                1, mode="drop"
-            )
-            if need_rdr:
-                # reader bitmask: set the slot's bit on its *first*
-                # granted read column of the record — a re-entrant read
-                # on a key the slot already read-holds increments rc
-                # (per-entry, symmetric with release) but the bit tracks
-                # distinct membership
-                already = (
-                    (keys == rkey[:, None])
-                    & s["granted"]
-                    & (modes == MODE_READ)
-                ).any(axis=1)
-                new_rd = g_rd_t & ~already
-                s["rdr"] = s["rdr"].at[
-                    jnp.where(new_rd, rkey, R), rdr_word
-                ].add(jnp.where(new_rd, rdr_bit, 0), mode="drop")
-        s["granted"] = s["granted"] | grant
-
-        # ------------------------------------------------ 8. deadlock logic
-        # (runs before cost charging so a wait-die "die" probe — a read of
-        # the holder's timestamp — costs latency but does not occupy the
-        # record's meta-data line the way a queue mutation does)
-        abort_dl = jnp.zeros((T,), jnp.bool_)
-        if dl != "none":
-            kptr_c = jnp.minimum(kptr, K - 1)[:, None]
-            waitkey = jnp.where(
-                (phase == ACQ)
-                & jnp.take_along_axis(
-                    s["want"] & ~s["granted"], kptr_c, axis=1
-                ).squeeze(1),
-                jnp.take_along_axis(keys, kptr_c, axis=1).squeeze(1),
-                KEY_SENTINEL,
-            )
-            waiting = waitkey != KEY_SENTINEL
-            mymode = jnp.take_along_axis(modes, kptr_c, axis=1).squeeze(1)
-            # adj[t,u]: t waits on a lock u holds in a conflicting mode
-            if use_csr:
-                # compact wait-for: the writer holding my key is one
-                # lock-table gather; read holders come from the carried
-                # per-record reader bitmask (bit u of word u // 32) —
-                # [T] + [T, W] gathers and a [T, T] bit extraction
-                # replace the dense [T, T, K] key-equality tensor. A
-                # read holder conflicts only with a write waiter; a
-                # write holder conflicts with everyone.
-                wt_c = jnp.minimum(waitkey, R - 1)
-                hw = s["wh"][wt_c]  # [T] writer of my key (-1 = none)
-                dig = s["rdr"][wt_c]  # [T, W] packed reader bits
-                rd_bits = jnp.take(dig, rdr_word, axis=1)  # [T, T]
-                adj_rd = ((rd_bits >> (slot_ids % 32)[None, :]) & 1) != 0
-                adj = (
-                    (
-                        (slot_ids[None, :] == hw[:, None])
-                        | (adj_rd & (mymode == MODE_WRITE)[:, None])
-                    )
-                    & waiting[:, None]
-                    & (slot_ids[None, :] != slot_ids[:, None])
-                    & (tid[None, :] >= 0)
-                )
-            else:
-                key_eq = keys[None, :, :] == waitkey[:, None, None]  # [t,u,k]
-                conflict = (mymode[:, None, None] == MODE_WRITE) | (
-                    modes[None, :, :] == MODE_WRITE
-                )
-                adj = (
-                    (key_eq & s["granted"][None, :, :] & conflict).any(-1)
-                    & waiting[:, None]
-                    & (slot_ids[None, :] != slot_ids[:, None])
-                    & (tid[None, :] >= 0)
-                )
-            if dl == "waitdie":
-                # a waiter dies whenever its wait-for edge points at an
-                # older holder — evaluated on every holder change (waiting
-                # on a younger holder is legal, so the edge must be
-                # re-checked when the lock changes hands); the "die" probe
-                # is a read of the holder's timestamp and is costed as
-                # latency only (no line occupancy) in stage 9
-                newly_waiting = waiting & ~waited
-                older_holder = (
-                    adj & (ts[None, :] < ts[:, None])
-                ).any(-1)
-                abort_dl = older_holder & waiting
-                dl_debt = dl_debt + jnp.where(
-                    newly_waiting, cm.waitdie_check_cycles, 0
-                )
-            else:
-                own = jnp.eye(T, dtype=jnp.bool_)
-                # one propagation step per round (dreadlocks-style digests)
-                reach = own | (adj @ s["reach"])
-                s["reach"] = jnp.where(waiting[:, None], reach, own)
-                in_cycle = (adj & s["reach"].T).any(-1)  # holder reaches me
-                # abort the youngest member of the detected cycle; waitfor
-                # and dreadlocks are logically equivalent detectors (paper
-                # §4.1) and differ only in their cost constants
-                scc = s["reach"] & s["reach"].T
-                scc_ts_max = jnp.max(
-                    jnp.where(scc & in_cycle[None, :], ts[None, :], -1),
-                    axis=1,
-                )
-                abort_dl = in_cycle & (ts >= scc_ts_max)
-                dl_debt = dl_debt + jnp.where(waiting, dl_wait_cycles, 0)
-            waited = waiting
-            # convert deadlock-handling debt into lane busy time
-            debt_rounds = dl_debt // cm.cycles_per_round
-            has_debt = debt_rounds > 0
-            busy_until = jnp.where(
-                has_debt, jnp.maximum(busy_until, r) + debt_rounds,
-                busy_until,
-            )
-            busy_kind = jnp.where(has_debt, CAT_DL, busy_kind)
-            dl_debt = dl_debt % cm.cycles_per_round
-
-            abort_dl = abort_dl & waiting
-            s["aborts_dl"] = s["aborts_dl"] + abort_dl.sum(dtype=jnp.int32)
-            s["wasted"] = s["wasted"] + jnp.where(abort_dl, kptr, 0).sum(
-                dtype=jnp.int32
-            )
-            phase = jnp.where(abort_dl, REL, phase)
-            committing = jnp.where(abort_dl, False, committing)
-            release_at = jnp.where(abort_dl, r, release_at)
-            s["want"] = s["want"] & ~abort_dl[:, None]
-
-        # ------------------------------------------------ 9. line-cost model
-        # Coherence physics for shared lock tables (paper §2.1): each record's
-        # CC meta-data line is a serially-reusable resource. Op service time
-        # grows with the number of cores recently touching the line ("sharer
-        # heat", estimated over epoch windows) and with line ping-pong (last
-        # toucher on a different core). Queue-mutating ops on a backlogged
-        # line wait behind it; wait-die "die" probes pay their own transfer
-        # latency but occupy nothing. ORTHRUS CC lanes are exempt:
-        # single-owner meta-data.
-        if not cfg.is_orthrus:
-            newop = newop2d  # fresh lock-table ops this round: reqs+releases
-            mutate = newop & ~abort_dl[:, None]  # dies don't enqueue
-            # per-key same-round contention via the carried agg_sum buffer
-            # (columns: active entries, new ops, queue mutations). One
-            # scatter-add per round cancels the previous round's
-            # contributions and applies this round's, so the buffer holds
-            # exactly "this round" when gathered and is never read before
-            # a pending mutation (no [R]-sized copy, see _state0).
-            active2d = pend2d | rel_entries
-            aidx = jnp.where(active2d, keys, R)
-            sum_upd = jnp.stack(
-                [active2d.astype(i32), newop.astype(i32),
-                 mutate.astype(i32)], axis=-1,
-            )  # [T, K, 3]
-            idx_cat = jnp.concatenate([s["agg_prev_idx"], aidx], axis=0)
-            upd_cat = jnp.concatenate([-s["agg_prev_upd"], sum_upd], axis=0)
-            agg_s = s["agg_sum"].at[idx_cat].add(upd_cat, mode="drop")
-            s["agg_sum"] = agg_s
-            s["agg_prev_idx"] = aidx
-            s["agg_prev_upd"] = sum_upd
-            e = r >> EPOCH_BITS
-            opk_r = jnp.minimum(jnp.where(newop, keys, 0), R - 1)
-            seg = agg_s[opk_r]  # [T, K, 3], this round's per-key totals
-            contend = seg[..., 0]
-            new_in_seg = seg[..., 1]
-            mut_in_seg = seg[..., 2]
-            heat_k = s["heat"][opk_r]  # [T, K, 3] = (ep, cnt_cur, cnt_prev)
-            ep_k = heat_k[..., 0]
-            cur_k = heat_k[..., 1]
-            prev_k = heat_k[..., 2]
-            line_k = s["line"][opk_r]  # [T, K, 2] = (lnf, last_lane)
-            sharers = jnp.where(
-                ep_k == e,
-                jnp.maximum(prev_k, cur_k),
-                jnp.where(ep_k == e - 1, cur_k, 0),
-            )
-            lane2d = jnp.broadcast_to(lane_of[:, None], (T, K))
-            remote = line_k[..., 1] != lane2d
-            coh = jnp.where(
-                remote,
-                cm.coherence_cycles_per_sharer
-                * jnp.clip(sharers, 1, cfg.n_exec - 1),
-                0,
-            )
-            if dl == "dreadlocks":
-                # waiters spin on the holders' digests: every queued waiter
-                # keeps the lock meta-data lines hot, so each op pays extra
-                # coherence proportional to the current queue (paper §4.4.1)
-                coh = coh + cm.dreadlocks_spin_cycles * jnp.maximum(
-                    contend - 1, 0
-                )
-            dur = rounds_of(lock_op_cycles + coh)
-            lnf_cur = line_k[..., 0]
-            backlog = jnp.maximum(jnp.where(mutate, lnf_cur - r, 0), 0)
-            charge = jnp.where(newop, backlog + dur, 0).sum(axis=1)
-            # occupancy: same-round queue mutations serialize on the line
-            occupy = jnp.where(mutate, mut_in_seg * dur, 0)
-            tgt = jnp.maximum(lnf_cur, r) + occupy
-            opk_heat = jnp.where(newop, opk_r, R)
-            # packed writes: lnf applies only at mutating entries (a die
-            # probe occupies nothing), masked inside the max via INT32_MIN;
-            # last_lane applies at every fresh op. Heat values are
-            # per-key-identical, so duplicate-index set is idempotent.
-            line_upd = jnp.stack(
-                [jnp.where(mutate, tgt, jnp.iinfo(jnp.int32).min), lane2d],
-                axis=-1,
-            )
-            s["line"] = s["line"].at[opk_heat].max(line_upd, mode="drop")
-            new_prev = jnp.where(
-                ep_k == e, prev_k, jnp.where(ep_k == e - 1, cur_k, 0)
-            )
-            new_cur = jnp.where(ep_k == e, cur_k, 0) + new_in_seg
-            heat_upd = jnp.stack(
-                [jnp.broadcast_to(e, new_cur.shape), new_cur, new_prev],
-                axis=-1,
-            )
-            s["heat"] = s["heat"].at[opk_heat].set(heat_upd, mode="drop")
-            charged = charge > 0
-            busy_until = jnp.where(
-                charged, jnp.maximum(busy_until, r) + charge,
-                busy_until,
-            )
-            busy_kind = jnp.where(charged, CAT_LOCK, busy_kind)
-
-        # ------------------------------------------------ 10. transitions
-        free = busy_until <= r
-        exec_rounds_one = rounds_of(exec_cycles_per_op)
-
-        if cfg.is_dynamic_2pl:
-            cur_granted = jnp.take_along_axis(
-                s["granted"], jnp.minimum(kptr, K - 1)[:, None], axis=1
-            ).squeeze(1)
-            go = (phase == ACQ) & free & cur_granted & ~abort_dl
-            last = go & (kptr + 1 >= nkeys)
-            extra = jnp.maximum(execops - nkeys, 0)
-            add = jnp.where(
-                go, exec_rounds_one + jnp.where(last, extra * exec_rounds_one, 0), 0
-            )
-            busy_until = jnp.where(
-                go, jnp.maximum(busy_until, r) + add, busy_until
-            )
-            busy_kind = jnp.where(go, CAT_EXEC, busy_kind)
-            kptr = jnp.where(go, kptr + 1, kptr)
-            phase = jnp.where(last, EXEC, phase)
-        elif cfg.protocol in ("deadlock_free", "partitioned_store"):
-            cur_granted = jnp.take_along_axis(
-                s["granted"], jnp.minimum(kptr, K - 1)[:, None], axis=1
-            ).squeeze(1)
-            go = (phase == ACQ) & free & cur_granted
-            kptr = jnp.where(go, kptr + 1, kptr)
-            alldone = go & (kptr >= nkeys)
-            phase = jnp.where(alldone, EXEC, phase)
-            busy_until = jnp.where(
-                alldone,
-                jnp.maximum(busy_until, r) + execops * exec_rounds_one,
-                busy_until,
-            )
-            busy_kind = jnp.where(alldone, CAT_EXEC, busy_kind)
-        else:  # orthrus
-            in_cur_group = (
-                (kk[None, :] >= ccptr[:, None])
-                & kvalid
-                & (ccids == jnp.take_along_axis(
-                    ccids, jnp.minimum(ccptr, K - 1)[:, None], axis=1))
-            )
-            grp_done = (
-                (phase == ACQ)
-                & jnp.where(in_cur_group, s["granted"], True).all(axis=1)
-            )
-            nxt_cc = jnp.where(
-                (kk[None, :] >= ccptr[:, None]) & kvalid & ~in_cur_group,
-                kk[None, :],
-                K,
-            ).min(axis=1)
-            more = grp_done & (nxt_cc < K)
-            ccptr = jnp.where(more, nxt_cc, ccptr)
-            s["adm_done"] = jnp.where(more[:, None], False, s["adm_done"])
-            phase = jnp.where(grp_done, MSG, phase)
-            msg_stage = jnp.where(grp_done, jnp.where(more, 0, 1), msg_stage)
-            msg_arrive = jnp.where(
-                grp_done, r + cm.msg_hop_rounds, msg_arrive
-            )
-            # response arrives -> READY
-            resp = (
-                (phase == MSG) & (msg_stage == 1) & (msg_arrive <= r)
-            )
-            phase = jnp.where(resp, READY, phase)
-            # exec-lane scheduling: oldest READY per idle lane starts
-            lane_busy = jax.ops.segment_sum(
-                ((phase == EXEC) & ~free).astype(jnp.int32),
-                lane_of,
-                num_segments=cfg.n_exec,
-            )
-            ready = phase == READY
-            ready_ts = jnp.where(ready, ts, jnp.iinfo(jnp.int32).max)
-            lane_min = jax.ops.segment_min(
-                ready_ts, lane_of, num_segments=cfg.n_exec
-            )
-            startx = (
-                ready
-                & (ready_ts == lane_min[lane_of])
-                & (lane_busy[lane_of] == 0)
-            )
-            # break ties (same ts impossible — tids unique) -> safe
-            phase = jnp.where(startx, EXEC, phase)
-            busy_until = jnp.where(
-                startx, r + execops * exec_rounds_one, busy_until
-            )
-            busy_kind = jnp.where(startx, CAT_EXEC, busy_kind)
-
-        # EXEC finished -> release (commit, or OLLP-miss abort+retry)
-        free = busy_until <= r
-        fin = (phase == EXEC) & free
-        is_miss = fin & miss & (attempt == 0)
-        s["aborts_ollp"] = s["aborts_ollp"] + is_miss.sum(dtype=jnp.int32)
-        s["wasted"] = s["wasted"] + jnp.where(is_miss, execops, 0).sum(
-            dtype=jnp.int32
-        )
-        phase = jnp.where(fin, REL, phase)
-        committing = jnp.where(fin, ~is_miss, committing)
-        rel_delay = cm.msg_hop_rounds if cfg.is_orthrus else 0
-        release_at = jnp.where(fin, r + rel_delay, release_at)
-        s["rel_done"] = jnp.where(fin[:, None], False, s["rel_done"])
-        s["want"] = s["want"] & ~fin[:, None]
-
-        # REL complete -> EMPTY (commit) or BACKOFF (retry). A slot leaves
-        # only after every lock it held has actually been released (the
-        # release scatter runs in stages 4/5 of a *subsequent* round).
-        rel_done_all = (
-            (phase == REL)
-            & (release_at <= r)
-            & ~(s["granted"]).any(axis=1)
-        )
-        com = rel_done_all & committing
-        s["commits"] = s["commits"] + com.sum(dtype=jnp.int32)
-        # metrics: commit-latency histogram (log-bucketed; bucket = count
-        # of powers of two <= latency). Commits only happen at executed
-        # rounds, so the scatter is bit-identical under event leaping.
-        lat = r - arrive
-        lat_b = jnp.sum(
-            lat[:, None] >= lat_pow2[None, :], axis=1, dtype=jnp.int32
-        )
-        s["lat_hist"] = s["lat_hist"].at[
-            jnp.where(com, lat_b, LAT_BUCKETS)
-        ].add(1, mode="drop")
-        aborting = rel_done_all & ~committing
-        if exp_backoff:
-            # bounded exponential backoff: base << attempt, shift-capped
-            # then clamped (deterministic integer math on C_ATTEMPT —
-            # cost_model.exp_backoff_rounds is the host oracle)
-            bo = jnp.minimum(
-                cm.abort_backoff_rounds
-                << jnp.minimum(attempt, BACKOFF_SHIFT_CAP),
-                p["pol_bo_max"],
-            )
-        else:
-            bo = cm.abort_backoff_rounds
-        if has_budget or policy == "deadline_shed":
-            # give-up paths: a retrying txn is dropped instead of backing
-            # off when its retry budget is spent (pol_sacrificed, checked
-            # first) or, under deadline_shed, when its end-to-end latency
-            # has already blown the deadline (pol_timedout)
-            give_up = jnp.zeros((T,), jnp.bool_)
-            if has_budget:
-                sac = aborting & (attempt + 1 >= p["pol_retry_budget"])
-                s["pol_sacrificed"] = (
-                    s["pol_sacrificed"] + sac.sum(dtype=i32)
-                )
-                give_up = give_up | sac
-            if policy == "deadline_shed":
-                timed = (
-                    aborting & ~give_up
-                    & (r - arrive > p["pol_deadline"])
-                )
-                s["pol_timedout"] = (
-                    s["pol_timedout"] + timed.sum(dtype=i32)
-                )
-                give_up = give_up | timed
-            leave = committing | give_up
-            drop_tid = com | give_up
-            back = aborting & ~give_up
-        else:
-            leave = committing
-            drop_tid = com
-            back = aborting
-        if exp_backoff:
-            s["pol_backoff_rounds"] = s["pol_backoff_rounds"] + jnp.where(
-                back, bo, 0
-            ).sum(dtype=i32)
-        phase = jnp.where(
-            rel_done_all, jnp.where(leave, EMPTY, BACKOFF), phase
-        )
-        tid = jnp.where(drop_tid, -1, tid)
-        busy_until = jnp.where(back, r + bo, busy_until)
-        s["want"] = jnp.where(rel_done_all[:, None], False, s["want"])
-
-        # ------------------------------------------------ 11. lane accounting
-        busy = busy_until > r
-        slot_cat = jnp.where(
-            busy,
-            busy_kind,
-            jnp.where(
-                (phase == ACQ) & (s["want"] & ~s["granted"]).any(axis=1),
-                CAT_WAIT,
-                jnp.where(
-                    (phase == MSG) | (phase == READY) | (phase == REL),
-                    CAT_MSG,
-                    CAT_IDLE,
-                ),
-            ),
-        )
-        if cfg.is_orthrus:
-            # a lane is "exec" if its running slot is busy executing; else
-            # classify by the most advanced outstanding slot state
-            lane_exec = jax.ops.segment_max(
-                (busy & (slot_cat == CAT_EXEC)).astype(jnp.int32), lane_of,
-                num_segments=cfg.n_exec,
-            )
-            lane_wait = jax.ops.segment_max(
-                (slot_cat == CAT_WAIT).astype(jnp.int32), lane_of,
-                num_segments=cfg.n_exec,
-            )
-            lane_msg = jax.ops.segment_max(
-                (slot_cat == CAT_MSG).astype(jnp.int32), lane_of,
-                num_segments=cfg.n_exec,
-            )
-            lane_cat = jnp.where(
-                lane_exec == 1,
-                CAT_EXEC,
-                jnp.where(lane_wait == 1, CAT_WAIT,
-                          jnp.where(lane_msg == 1, CAT_MSG, CAT_IDLE)),
-            )
-            cat_counts = jax.ops.segment_sum(
-                jnp.ones((cfg.n_exec,), jnp.int32),
-                lane_cat,
-                num_segments=NCAT,
-            )
-        else:
-            cat_counts = jax.ops.segment_sum(
-                jnp.ones((T,), jnp.int32), slot_cat, num_segments=NCAT
-            )
-
-        # ------------------------------------------------ 12. event leap
-        # Advance straight to the next round at which any slot can act.
-        # Every skipped round is provably a no-op: every per-slot timer
-        # (busy_until / msg_arrive / release_at) lies beyond it and no slot
-        # is in a phase that acts unconditionally each round. Lane
-        # accounting is exact because the post-transition lane state (the
-        # `cat_counts` just computed) persists unchanged through the gap.
-        if cfg.event_leap:
-            busy2 = busy_until > r
-            free2 = ~busy2
-            # future per-slot timers; a busy expiry is always an event (it
-            # changes lane accounting even when no transition follows)
-            cand = jnp.where(busy2, busy_until, _IMAX)
-            # admission, release processing and message arrival ignore the
-            # busy timer (stages 1, 4, 5 have no `free` gate), so their
-            # timers and ready-to-act states are tracked unconditionally
-            cand = jnp.minimum(cand, jnp.where(
-                (phase == MSG) & (msg_arrive > r), msg_arrive, _IMAX))
-            cand = jnp.minimum(cand, jnp.where(
-                (phase == REL) & (release_at > r), release_at, _IMAX))
+            # ------------------------------------------ 1+2. admission & retry
+            # New admissions (EMPTY slots) and backoff->retry (BACKOFF slots
+            # whose timer expired) are disjoint and share most column resets,
+            # so they run as one fused masked update.
+            empty = phase == EMPTY
             if lane_stream is None:
+                rank = jnp.cumsum(empty.astype(i32)) - 1
+                new_tid = s["next_txn"] + rank
                 if open_arrival:
-                    # the earliest admissible txn is global id next_txn
-                    # (post-admission); an empty slot acts once it has
-                    # arrived, and its arrival round is the wake-up
-                    # event until then (arrival is monotone in g, so no
-                    # admission can happen sooner)
-                    g0 = s["next_txn"]
-                    arr0 = arr_of(g0)
+                    # global txn id g arrives with its epoch; arrival is
+                    # monotone in g, so the admitted set is a prefix of the
+                    # ranked empty slots and tids stay contiguous
+                    arr_t = arr_of(new_tid)
+                    adm = empty & (arr_t <= r)
                     if policy == "token_bucket":
-                        # admission additionally waits for token g0:
-                        # earliest grant round is the host oracle
-                        # cost_model.token_ready_round
-                        arr0 = jnp.maximum(arr0, _sat_mul(
-                            jnp.maximum(g0 - p["pol_tb_burst"] + 1, 0),
-                            p["pol_tb_iv"],
-                        ))
-                    can_adm = jnp.broadcast_to(arr0 <= r + 1, (T,))
-                    cand = jnp.minimum(cand, jnp.where(
-                        (phase == EMPTY).any(), arr0, _IMAX))
-                    # policy drop events are wake-ups in their own right
-                    # (not gated on an EMPTY slot): the next drop round
-                    # is closed-form in next_txn, so leaping lands on
-                    # it exactly and stage 1a stays dense-identical
-                    if policy == "bounded_backlog":
-                        cand = jnp.minimum(
-                            cand, arr_of(g0 + p["pol_cap"])
+                        # backpressure, no drops: txn g additionally waits
+                        # for token g — the bucket starts with token_burst
+                        # and refills one every token_interval_rounds
+                        # (cost_model.token_grant is the host oracle). The
+                        # gate loosens as g falls, so the admitted set is
+                        # still a prefix of the ranked empty slots.
+                        adm = adm & (
+                            new_tid
+                            < p["pol_tb_burst"] + r // p["pol_tb_iv"]
                         )
-                    elif policy == "deadline_shed":
-                        cand = jnp.minimum(
-                            cand, arr0 + p["pol_deadline"] + 1
+                        s["pol_tb_adm"] = s["pol_tb_adm"] + adm.sum(
+                            dtype=i32
                         )
                 else:
-                    can_adm = jnp.ones((T,), jnp.bool_)
+                    adm = empty
+                new_widx = new_tid % N
+                s["next_txn"] = s["next_txn"] + adm.sum(dtype=i32)
             else:
-                can_adm = (
-                    lane_stream[slot_ids, lane_ctr % meta.lane_cols] >= 0
-                )
-            act_next = (
-                ((phase == EMPTY) & can_adm)
-                | ((phase == MSG) & (msg_arrive <= r))
-                | ((phase == REL) & (release_at <= r))
-                | (free2 & ((phase == INIT) | (phase == BACKOFF)))
+                # H-Store routing: each worker lane pulls the next txn homed to
+                # its partition (lanes with no homed txns stay idle).
+                M = meta.lane_cols
+                new_widx = lane_stream[slot_ids, lane_ctr % M]
+                adm = empty & (new_widx >= 0)
+                new_tid = lane_ctr * T + slot_ids
+                lane_ctr = jnp.where(adm, lane_ctr + 1, lane_ctr)
+                s["next_txn"] = s["next_txn"] + adm.sum(dtype=i32)
+            retry = (phase == BACKOFF) & free
+            reset = adm | retry
+            widx = jnp.where(adm, new_widx, widx)
+            tid = jnp.where(adm, new_tid, tid)
+            ts = jnp.where(adm, new_tid, ts)
+            # metrics: stamp the txn's arrival round — its epoch arrival
+            # under open arrival (latency then includes queueing delay), the
+            # admission round under closed loop. Retries keep the stamp, so
+            # latency spans aborts end-to-end.
+            arrive = jnp.where(adm, arr_t if open_arrival else r, arrive)
+            attempt = jnp.where(adm, 0, jnp.where(retry, attempt + 1, attempt))
+            # per-slot workload columns for the loaded txns (the scalar
+            # per-txn fields ride one fused [N, 4] gather)
+            wsafe = jnp.where(tid >= 0, widx % N, 0)
+            keys = wkeys[wsafe]
+            modes = wmodes[wsafe]
+            ccids = wpart[wsafe] % n_cc
+            sc = sc_all[wsafe]
+            nkeys = sc[:, 0]
+            execops = sc[:, 1]
+            ollp = sc[:, 2] != 0
+            miss = sc[:, 3] != 0
+            kvalid = kk[None, :] < nkeys[:, None]
+            init_busy = rounds_of(
+                cm.txn_fixed_cycles
+                + jnp.where(ollp, cm.recon_cycles, 0)
             )
+            phase = jnp.where(reset, INIT, phase)
+            busy_until = jnp.where(
+                adm,
+                r + init_busy,
+                jnp.where(retry, r + rounds_of(cm.txn_fixed_cycles), busy_until),
+            )
+            busy_kind = jnp.where(reset, CAT_LOCK, busy_kind)
+            for f in ("want", "granted", "adm_done", "rel_done"):
+                s[f] = jnp.where(reset[:, None], False, s[f])
+            kptr = jnp.where(reset, 0, kptr)
+            ccptr = jnp.where(reset, 0, ccptr)
+            waited = jnp.where(reset, False, waited)
+
+            free = busy_until <= r
+
+        # ------------------------------------------------ 3. INIT -> acquire
+        with jax.named_scope("stage.acquire"):
+            start = (phase == INIT) & free & (tid >= 0)
             if cfg.is_orthrus:
-                # a READY slot starts the round its lane goes idle; while
-                # the lane runs another slot, that slot's busy_until is the
-                # wake-up event (already a candidate above)
-                lane_exec_busy = jax.ops.segment_max(
-                    ((phase == EXEC) & busy2).astype(jnp.int32), lane_of,
+                phase = jnp.where(start, MSG, phase)
+                msg_stage = jnp.where(start, 0, msg_stage)
+                msg_arrive = jnp.where(start, r + cm.msg_hop_rounds, msg_arrive)
+            else:
+                phase = jnp.where(start, ACQ, phase)
+
+        # ------------------------------------------------ 4. ORTHRUS CC work
+        with jax.named_scope("stage.cc"):
+            if cfg.is_orthrus:
+                # -- admission of acquire-messages and release-messages, bounded
+                #    by each CC lane's per-round key-op capacity, in ts order.
+                in_cur_group = (
+                    (kk[None, :] >= ccptr[:, None])
+                    & kvalid
+                    & (ccids == jnp.take_along_axis(
+                        ccids, jnp.minimum(ccptr, K - 1)[:, None], axis=1))
+                )
+                acq_cand = (
+                    (phase == MSG) & (msg_stage == 0) & (msg_arrive <= r)
+                )
+                acq_keys = acq_cand[:, None] & in_cur_group & ~s["adm_done"]
+                rel_cand = (phase == REL) & (release_at <= r)
+                rel_keys = rel_cand[:, None] & s["granted"] & ~s["rel_done"]
+                # Rank every active entry within its CC lane by (ts, key slot)
+                # — the admission order — without sorting all T*K entries: a
+                # slot's entries share its (unique) ts, so a [T] slot sort plus
+                # per-CC prefix counts reproduces the (cc, ts, entry) rank
+                # exactly at a fraction of the cost.
+                act2d = acq_keys | rel_keys  # [T, K]
+                cc_act = jnp.where(act2d, ccids, n_cc)
+                cnt_tc = (
+                    jnp.zeros((T, n_cc + 1), jnp.int32)
+                    .at[jnp.broadcast_to(slot_ids[:, None], (T, K)), cc_act]
+                    .add(1)
+                )
+                slot_order = jnp.argsort(ts, stable=True)  # ts unique
+                cnt_sorted = cnt_tc[slot_order]
+                excl_sorted = jnp.cumsum(cnt_sorted, axis=0) - cnt_sorted
+                excl = jnp.zeros_like(excl_sorted).at[slot_order].set(excl_sorted)
+                base_rank = jnp.take_along_axis(excl, cc_act, axis=1)
+                same_cc_earlier = (
+                    (cc_act[:, :, None] == cc_act[:, None, :])
+                    & act2d[:, None, :]
+                    & (kk[None, None, :] < kk[None, :, None])
+                )
+                within = same_cc_earlier.sum(-1, dtype=jnp.int32)
+                seg_pos2d = base_rank + within + 1  # 1-based within CC lane
+                proc2d = (seg_pos2d <= cap_keys) & act2d
+                s["adm_done"] = s["adm_done"] | (proc2d & acq_keys.reshape(T, K))
+                # group fully admitted -> requests live in the CC's lock table
+                grp_all = jnp.where(in_cur_group, s["adm_done"], True).all(axis=1)
+                admit_now = acq_cand & grp_all
+                new_want = admit_now[:, None] & in_cur_group
+                phase = jnp.where(admit_now, ACQ, phase)
+                # release processing
+                do_rel = proc2d & rel_keys.reshape(T, K)
+                rel_k = jnp.where(do_rel, keys, 0)
+                is_wr = do_rel & (modes == MODE_WRITE)
+                s["wh"] = s["wh"].at[jnp.where(is_wr, rel_k, R)].set(
+                    -1, mode="drop"
+                )
+                is_rd = do_rel & (modes == MODE_READ)
+                s["rc"] = s["rc"].at[jnp.where(is_rd, rel_k, R)].add(
+                    -1, mode="drop"
+                )
+                s["rel_done"] = s["rel_done"] | do_rel
+                s["granted"] = s["granted"] & ~do_rel
+            else:
+                new_want = jnp.zeros((T, K), jnp.bool_)
+
+        # ------------------------------------------------ 5. shared releases
+        with jax.named_scope("stage.release"):
+            rel_entries = jnp.zeros((T, K), jnp.bool_)
+            if not cfg.is_orthrus:
+                rel_now = (phase == REL) & (release_at <= r)
+                rel_entries = rel_now[:, None] & s["granted"]
+                rel_k = jnp.where(rel_entries, keys, 0)
+                is_wr = rel_entries & (modes == MODE_WRITE)
+                s["wh"] = s["wh"].at[jnp.where(is_wr, rel_k, R)].set(
+                    -1, mode="drop"
+                )
+                is_rd = rel_entries & (modes == MODE_READ)
+                s["rc"] = s["rc"].at[jnp.where(is_rd, rel_k, R)].add(
+                    -1, mode="drop"
+                )
+                if need_rdr:
+                    # clear the slot's reader bit once per *distinct*
+                    # released read key: a slot releases all its granted
+                    # entries at once, and re-entrant reads may hold several
+                    # columns on one record — only the first contributes
+                    dup = (
+                        (keys[:, :, None] == keys[:, None, :])
+                        & is_rd[:, None, :]
+                        & (kk[None, None, :] < kk[None, :, None])
+                    ).any(-1)
+                    first_rd = is_rd & ~dup
+                    s["rdr"] = s["rdr"].at[
+                        jnp.where(first_rd, rel_k, R),
+                        jnp.broadcast_to(rdr_word[:, None], (T, K)),
+                    ].add(
+                        jnp.where(first_rd, -rdr_bit[:, None], 0), mode="drop"
+                    )
+                s["granted"] = s["granted"] & ~rel_entries
+
+        # ------------------------------------------------ 6. requests: want
+        with jax.named_scope("stage.request"):
+            if cfg.is_orthrus:
+                s["want"] = s["want"] | new_want
+                want_new = new_want
+            else:
+                # 2PL/DF/pstore: single in-flight request at kptr when ACQ & free
+                at_k = kk[None, :] == kptr[:, None]
+                need = (
+                    ((phase == ACQ) & free)[:, None]
+                    & at_k
+                    & kvalid
+                    & ~s["granted"]
+                    & ~s["want"]
+                )
+                want_new = need
+                s["want"] = s["want"] | need
+
+            # assign enqueue order stamps to new queue entries
+            if cfg.is_orthrus:
+                flat_new = want_new.reshape(-1)
+                new_rank = jnp.cumsum(flat_new.astype(jnp.int32)) - 1
+                enq_val = (s["enq_ctr"] + new_rank).reshape(T, K)
+                s["enq"] = jnp.where(want_new, enq_val, s["enq"])
+                n_new = flat_new.sum(dtype=jnp.int32)
+            else:
+                # <= 1 new request per slot: rank over [T], same stamps as the
+                # row-major flat cumsum (one entry per row)
+                new_t = want_new.any(axis=1)
+                new_rank = jnp.cumsum(new_t.astype(jnp.int32)) - 1
+                s["enq"] = jnp.where(
+                    want_new, (s["enq_ctr"] + new_rank)[:, None], s["enq"]
+                )
+                n_new = new_t.sum(dtype=jnp.int32)
+            # releases consume stamp ids too (bit-compatible with the sorted
+            # grant pass, where they participate as REQ_RELEASE entries)
+            s["enq_ctr"] = s["enq_ctr"] + n_new + rel_entries.sum(dtype=jnp.int32)
+
+        # ------------------------------------------------ 7. grant pass
+        with jax.named_scope("stage.grant"):
+            # Requests are live only while their slot is acquiring.
+            pend2d = s["want"] & ~s["granted"] & (phase == ACQ)[:, None]
+            newop2d = want_new | rel_entries  # fresh lock-table ops this round
+            if cfg.is_orthrus:
+                ent_kind = jnp.where(
+                    pend2d,
+                    jnp.where(modes == MODE_WRITE, REQ_WRITE, REQ_READ),
+                    jnp.where(rel_entries, REQ_RELEASE, REQ_NONE),
+                ).reshape(-1)
+                ent_key = jnp.where(
+                    (pend2d | rel_entries), keys, KEY_SENTINEL
+                ).reshape(-1)
+                ent_enq = s["enq"].reshape(-1)
+                safe = jnp.minimum(ent_key, R - 1)
+                in_rng = ent_key < R
+                with jax.named_scope("kernel.lock_grant"):
+                    if use_pallas:
+                        # the Pallas segmented-grant kernel (compiled on
+                        # TPU/GPU, interpreted elsewhere — see
+                        # kernels.resolve_interpret); bit-identical to the jnp
+                        # path below, which is its oracle
+                        g_flat, _cont = _lock_grant(
+                            ent_key, ent_enq, ent_kind, s["wh"], s["rc"],
+                            num_records=R, block_n=grant_block,
+                        )
+                        grant = g_flat.reshape(T, K)
+                    else:
+                        wh_free = (s["wh"][safe] == -1) & in_rng
+                        rcv = jnp.where(in_rng, s["rc"][safe], 0)
+                        order = lex_order(ent_key, ent_enq)
+                        inv = inverse_permutation(order)
+                        g_sorted, _cont, _new = segmented_grant(
+                            ent_key[order],
+                            ent_enq[order],
+                            ent_kind[order],
+                            wh_free[order],
+                            rcv[order],
+                        )
+                        grant = g_sorted[inv].reshape(T, K)
+                # re-entrant grants bypass the FIFO: a slot re-requesting a key
+                # it already write-holds is granted immediately (real
+                # transactions touch the same row more than once; without this
+                # they would deadlock on their own lock)
+                ent_slot = jnp.broadcast_to(slot_ids[:, None], (T, K)).reshape(-1)
+                self_grant = (
+                    (ent_kind != REQ_NONE)
+                    & (ent_kind != REQ_RELEASE)
+                    & in_rng
+                    & (s["wh"][safe] == ent_slot)
+                )
+                grant = grant | self_grant.reshape(T, K)
+
+                # apply grants to the lock table
+                gk = jnp.where(grant, keys, 0)
+                g_wr = grant & (modes == MODE_WRITE)
+                g_rd = grant & (modes == MODE_READ)
+                holder = jnp.broadcast_to(slot_ids[:, None], (T, K))
+                s["wh"] = s["wh"].at[jnp.where(g_wr, gk, R)].set(
+                    holder, mode="drop"
+                )
+                s["rc"] = s["rc"].at[jnp.where(g_rd, gk, R)].add(1, mode="drop")
+            else:
+                # single pending request per slot, at column kptr: FIFO
+                # decisions among the <= T compact requests via an all-pairs
+                # [T, T] key comparison — no sort, no scatter
+                kptr_c = jnp.minimum(kptr, K - 1)[:, None]
+                pend_t = jnp.take_along_axis(pend2d, kptr_c, axis=1).squeeze(1)
+                rkey = jnp.take_along_axis(keys, kptr_c, axis=1).squeeze(1)
+                renq = jnp.take_along_axis(s["enq"], kptr_c, axis=1).squeeze(1)
+                rmode = jnp.take_along_axis(modes, kptr_c, axis=1).squeeze(1)
+                is_wr_req = pend_t & (rmode == MODE_WRITE)
+                if use_csr:
+                    # compact CSR grant: sort the <= T pending requests by
+                    # (key, stamp), take segmented minima of the enqueue
+                    # stamps, and scatter them back to slot order — work
+                    # sized to the request list (O(T log T)) instead of the
+                    # all-pairs [T, T] stamp comparison below. Non-pending
+                    # slots sort to a sentinel segment whose minima are
+                    # never read (grant_t is masked by pend_t).
+                    skey = jnp.where(pend_t, rkey, _IMAX)
+                    order = lex_order(skey, renq)
+                    ks = skey[order]
+                    eqs = renq[order]
+                    seg_start = jnp.concatenate(
+                        [jnp.ones((1,), jnp.bool_), ks[1:] != ks[:-1]]
+                    )
+                    seg_id = jnp.cumsum(seg_start.astype(i32)) - 1
+                    min_req_seg = jax.ops.segment_min(
+                        eqs, seg_id, num_segments=T
+                    )
+                    min_wr_seg = jax.ops.segment_min(
+                        jnp.where(is_wr_req[order], eqs, _IMAX), seg_id,
+                        num_segments=T,
+                    )
+                    min_req = (
+                        jnp.zeros((T,), i32).at[order].set(min_req_seg[seg_id])
+                    )
+                    min_wr = (
+                        jnp.zeros((T,), i32).at[order].set(min_wr_seg[seg_id])
+                    )
+                else:
+                    same_key = (rkey[None, :] == rkey[:, None]) & pend_t[None, :]
+                    enq_b = jnp.broadcast_to(renq[None, :], (T, T))
+                    min_wr = jnp.min(
+                        jnp.where(same_key & is_wr_req[None, :], enq_b, _IMAX),
+                        axis=1,
+                    )
+                    min_req = jnp.min(jnp.where(same_key, enq_b, _IMAX), axis=1)
+                rkey_c = jnp.minimum(rkey, R - 1)
+                whv = s["wh"][rkey_c]
+                rc_t = s["rc"][rkey_c]
+                wh_free_t = whv == -1
+                # read grant: write-free record, no older write request queued;
+                # write grant: write-free, zero read holders, oldest request.
+                # enq stamps are unique, so strict compares are exact.
+                grant_rd = wh_free_t & (min_wr > renq)
+                grant_wr = wh_free_t & (rc_t == 0) & (min_req == renq)
+                grant_t = pend_t & jnp.where(
+                    rmode == MODE_WRITE, grant_wr, grant_rd
+                )
+                # re-entrant grants bypass the FIFO (see the ORTHRUS path)
+                grant_t = grant_t | (pend_t & (whv == slot_ids))
+                grant = pend2d & grant_t[:, None]
+
+                # apply grants to the lock table ([T]-sized scatters: only the
+                # kptr column can be granted)
+                g_wr_t = grant_t & (rmode == MODE_WRITE)
+                g_rd_t = grant_t & (rmode == MODE_READ)
+                s["wh"] = s["wh"].at[jnp.where(g_wr_t, rkey, R)].set(
+                    slot_ids, mode="drop"
+                )
+                s["rc"] = s["rc"].at[jnp.where(g_rd_t, rkey, R)].add(
+                    1, mode="drop"
+                )
+                if need_rdr:
+                    # reader bitmask: set the slot's bit on its *first*
+                    # granted read column of the record — a re-entrant read
+                    # on a key the slot already read-holds increments rc
+                    # (per-entry, symmetric with release) but the bit tracks
+                    # distinct membership
+                    already = (
+                        (keys == rkey[:, None])
+                        & s["granted"]
+                        & (modes == MODE_READ)
+                    ).any(axis=1)
+                    new_rd = g_rd_t & ~already
+                    s["rdr"] = s["rdr"].at[
+                        jnp.where(new_rd, rkey, R), rdr_word
+                    ].add(jnp.where(new_rd, rdr_bit, 0), mode="drop")
+            s["granted"] = s["granted"] | grant
+
+        # ------------------------------------------------ 8. deadlock logic
+        with jax.named_scope("stage.deadlock"):
+            # (runs before cost charging so a wait-die "die" probe — a read of
+            # the holder's timestamp — costs latency but does not occupy the
+            # record's meta-data line the way a queue mutation does)
+            abort_dl = jnp.zeros((T,), jnp.bool_)
+            if dl != "none":
+                kptr_c = jnp.minimum(kptr, K - 1)[:, None]
+                waitkey = jnp.where(
+                    (phase == ACQ)
+                    & jnp.take_along_axis(
+                        s["want"] & ~s["granted"], kptr_c, axis=1
+                    ).squeeze(1),
+                    jnp.take_along_axis(keys, kptr_c, axis=1).squeeze(1),
+                    KEY_SENTINEL,
+                )
+                waiting = waitkey != KEY_SENTINEL
+                mymode = jnp.take_along_axis(modes, kptr_c, axis=1).squeeze(1)
+                # adj[t,u]: t waits on a lock u holds in a conflicting mode
+                if use_csr:
+                    # compact wait-for: the writer holding my key is one
+                    # lock-table gather; read holders come from the carried
+                    # per-record reader bitmask (bit u of word u // 32) —
+                    # [T] + [T, W] gathers and a [T, T] bit extraction
+                    # replace the dense [T, T, K] key-equality tensor. A
+                    # read holder conflicts only with a write waiter; a
+                    # write holder conflicts with everyone.
+                    wt_c = jnp.minimum(waitkey, R - 1)
+                    hw = s["wh"][wt_c]  # [T] writer of my key (-1 = none)
+                    dig = s["rdr"][wt_c]  # [T, W] packed reader bits
+                    rd_bits = jnp.take(dig, rdr_word, axis=1)  # [T, T]
+                    adj_rd = ((rd_bits >> (slot_ids % 32)[None, :]) & 1) != 0
+                    adj = (
+                        (
+                            (slot_ids[None, :] == hw[:, None])
+                            | (adj_rd & (mymode == MODE_WRITE)[:, None])
+                        )
+                        & waiting[:, None]
+                        & (slot_ids[None, :] != slot_ids[:, None])
+                        & (tid[None, :] >= 0)
+                    )
+                else:
+                    key_eq = keys[None, :, :] == waitkey[:, None, None]  # [t,u,k]
+                    conflict = (mymode[:, None, None] == MODE_WRITE) | (
+                        modes[None, :, :] == MODE_WRITE
+                    )
+                    adj = (
+                        (key_eq & s["granted"][None, :, :] & conflict).any(-1)
+                        & waiting[:, None]
+                        & (slot_ids[None, :] != slot_ids[:, None])
+                        & (tid[None, :] >= 0)
+                    )
+                if dl == "waitdie":
+                    # a waiter dies whenever its wait-for edge points at an
+                    # older holder — evaluated on every holder change (waiting
+                    # on a younger holder is legal, so the edge must be
+                    # re-checked when the lock changes hands); the "die" probe
+                    # is a read of the holder's timestamp and is costed as
+                    # latency only (no line occupancy) in stage 9
+                    newly_waiting = waiting & ~waited
+                    older_holder = (
+                        adj & (ts[None, :] < ts[:, None])
+                    ).any(-1)
+                    abort_dl = older_holder & waiting
+                    dl_debt = dl_debt + jnp.where(
+                        newly_waiting, cm.waitdie_check_cycles, 0
+                    )
+                else:
+                    own = jnp.eye(T, dtype=jnp.bool_)
+                    # one propagation step per round (dreadlocks-style digests)
+                    reach = own | (adj @ s["reach"])
+                    s["reach"] = jnp.where(waiting[:, None], reach, own)
+                    in_cycle = (adj & s["reach"].T).any(-1)  # holder reaches me
+                    # abort the youngest member of the detected cycle; waitfor
+                    # and dreadlocks are logically equivalent detectors (paper
+                    # §4.1) and differ only in their cost constants
+                    scc = s["reach"] & s["reach"].T
+                    scc_ts_max = jnp.max(
+                        jnp.where(scc & in_cycle[None, :], ts[None, :], -1),
+                        axis=1,
+                    )
+                    abort_dl = in_cycle & (ts >= scc_ts_max)
+                    dl_debt = dl_debt + jnp.where(waiting, dl_wait_cycles, 0)
+                waited = waiting
+                # convert deadlock-handling debt into lane busy time
+                debt_rounds = dl_debt // cm.cycles_per_round
+                has_debt = debt_rounds > 0
+                busy_until = jnp.where(
+                    has_debt, jnp.maximum(busy_until, r) + debt_rounds,
+                    busy_until,
+                )
+                busy_kind = jnp.where(has_debt, CAT_DL, busy_kind)
+                dl_debt = dl_debt % cm.cycles_per_round
+
+                abort_dl = abort_dl & waiting
+                s["aborts_dl"] = s["aborts_dl"] + abort_dl.sum(dtype=jnp.int32)
+                s["wasted"] = s["wasted"] + jnp.where(abort_dl, kptr, 0).sum(
+                    dtype=jnp.int32
+                )
+                phase = jnp.where(abort_dl, REL, phase)
+                committing = jnp.where(abort_dl, False, committing)
+                release_at = jnp.where(abort_dl, r, release_at)
+                s["want"] = s["want"] & ~abort_dl[:, None]
+
+        # ------------------------------------------------ 9. line-cost model
+        with jax.named_scope("stage.line_cost"):
+            # Coherence physics for shared lock tables (paper §2.1): each record's
+            # CC meta-data line is a serially-reusable resource. Op service time
+            # grows with the number of cores recently touching the line ("sharer
+            # heat", estimated over epoch windows) and with line ping-pong (last
+            # toucher on a different core). Queue-mutating ops on a backlogged
+            # line wait behind it; wait-die "die" probes pay their own transfer
+            # latency but occupy nothing. ORTHRUS CC lanes are exempt:
+            # single-owner meta-data.
+            if not cfg.is_orthrus:
+                newop = newop2d  # fresh lock-table ops this round: reqs+releases
+                mutate = newop & ~abort_dl[:, None]  # dies don't enqueue
+                # per-key same-round contention via the carried agg_sum buffer
+                # (columns: active entries, new ops, queue mutations). One
+                # scatter-add per round cancels the previous round's
+                # contributions and applies this round's, so the buffer holds
+                # exactly "this round" when gathered and is never read before
+                # a pending mutation (no [R]-sized copy, see _state0).
+                active2d = pend2d | rel_entries
+                aidx = jnp.where(active2d, keys, R)
+                sum_upd = jnp.stack(
+                    [active2d.astype(i32), newop.astype(i32),
+                     mutate.astype(i32)], axis=-1,
+                )  # [T, K, 3]
+                idx_cat = jnp.concatenate([s["agg_prev_idx"], aidx], axis=0)
+                upd_cat = jnp.concatenate([-s["agg_prev_upd"], sum_upd], axis=0)
+                agg_s = s["agg_sum"].at[idx_cat].add(upd_cat, mode="drop")
+                s["agg_sum"] = agg_s
+                s["agg_prev_idx"] = aidx
+                s["agg_prev_upd"] = sum_upd
+                e = r >> EPOCH_BITS
+                opk_r = jnp.minimum(jnp.where(newop, keys, 0), R - 1)
+                seg = agg_s[opk_r]  # [T, K, 3], this round's per-key totals
+                contend = seg[..., 0]
+                new_in_seg = seg[..., 1]
+                mut_in_seg = seg[..., 2]
+                heat_k = s["heat"][opk_r]  # [T, K, 3] = (ep, cnt_cur, cnt_prev)
+                ep_k = heat_k[..., 0]
+                cur_k = heat_k[..., 1]
+                prev_k = heat_k[..., 2]
+                line_k = s["line"][opk_r]  # [T, K, 2] = (lnf, last_lane)
+                sharers = jnp.where(
+                    ep_k == e,
+                    jnp.maximum(prev_k, cur_k),
+                    jnp.where(ep_k == e - 1, cur_k, 0),
+                )
+                lane2d = jnp.broadcast_to(lane_of[:, None], (T, K))
+                remote = line_k[..., 1] != lane2d
+                coh = jnp.where(
+                    remote,
+                    cm.coherence_cycles_per_sharer
+                    * jnp.clip(sharers, 1, cfg.n_exec - 1),
+                    0,
+                )
+                if dl == "dreadlocks":
+                    # waiters spin on the holders' digests: every queued waiter
+                    # keeps the lock meta-data lines hot, so each op pays extra
+                    # coherence proportional to the current queue (paper §4.4.1)
+                    coh = coh + cm.dreadlocks_spin_cycles * jnp.maximum(
+                        contend - 1, 0
+                    )
+                dur = rounds_of(lock_op_cycles + coh)
+                lnf_cur = line_k[..., 0]
+                backlog = jnp.maximum(jnp.where(mutate, lnf_cur - r, 0), 0)
+                charge = jnp.where(newop, backlog + dur, 0).sum(axis=1)
+                # occupancy: same-round queue mutations serialize on the line
+                occupy = jnp.where(mutate, mut_in_seg * dur, 0)
+                tgt = jnp.maximum(lnf_cur, r) + occupy
+                opk_heat = jnp.where(newop, opk_r, R)
+                # packed writes: lnf applies only at mutating entries (a die
+                # probe occupies nothing), masked inside the max via INT32_MIN;
+                # last_lane applies at every fresh op. Heat values are
+                # per-key-identical, so duplicate-index set is idempotent.
+                line_upd = jnp.stack(
+                    [jnp.where(mutate, tgt, jnp.iinfo(jnp.int32).min), lane2d],
+                    axis=-1,
+                )
+                s["line"] = s["line"].at[opk_heat].max(line_upd, mode="drop")
+                new_prev = jnp.where(
+                    ep_k == e, prev_k, jnp.where(ep_k == e - 1, cur_k, 0)
+                )
+                new_cur = jnp.where(ep_k == e, cur_k, 0) + new_in_seg
+                heat_upd = jnp.stack(
+                    [jnp.broadcast_to(e, new_cur.shape), new_cur, new_prev],
+                    axis=-1,
+                )
+                s["heat"] = s["heat"].at[opk_heat].set(heat_upd, mode="drop")
+                charged = charge > 0
+                busy_until = jnp.where(
+                    charged, jnp.maximum(busy_until, r) + charge,
+                    busy_until,
+                )
+                busy_kind = jnp.where(charged, CAT_LOCK, busy_kind)
+
+        # ------------------------------------------------ 10. transitions
+        with jax.named_scope("stage.transition"):
+            free = busy_until <= r
+            exec_rounds_one = rounds_of(exec_cycles_per_op)
+
+            if cfg.is_dynamic_2pl:
+                cur_granted = jnp.take_along_axis(
+                    s["granted"], jnp.minimum(kptr, K - 1)[:, None], axis=1
+                ).squeeze(1)
+                go = (phase == ACQ) & free & cur_granted & ~abort_dl
+                last = go & (kptr + 1 >= nkeys)
+                extra = jnp.maximum(execops - nkeys, 0)
+                add = jnp.where(
+                    go, exec_rounds_one + jnp.where(last, extra * exec_rounds_one, 0), 0
+                )
+                busy_until = jnp.where(
+                    go, jnp.maximum(busy_until, r) + add, busy_until
+                )
+                busy_kind = jnp.where(go, CAT_EXEC, busy_kind)
+                kptr = jnp.where(go, kptr + 1, kptr)
+                phase = jnp.where(last, EXEC, phase)
+            elif cfg.protocol in ("deadlock_free", "partitioned_store"):
+                cur_granted = jnp.take_along_axis(
+                    s["granted"], jnp.minimum(kptr, K - 1)[:, None], axis=1
+                ).squeeze(1)
+                go = (phase == ACQ) & free & cur_granted
+                kptr = jnp.where(go, kptr + 1, kptr)
+                alldone = go & (kptr >= nkeys)
+                phase = jnp.where(alldone, EXEC, phase)
+                busy_until = jnp.where(
+                    alldone,
+                    jnp.maximum(busy_until, r) + execops * exec_rounds_one,
+                    busy_until,
+                )
+                busy_kind = jnp.where(alldone, CAT_EXEC, busy_kind)
+            else:  # orthrus
+                in_cur_group = (
+                    (kk[None, :] >= ccptr[:, None])
+                    & kvalid
+                    & (ccids == jnp.take_along_axis(
+                        ccids, jnp.minimum(ccptr, K - 1)[:, None], axis=1))
+                )
+                grp_done = (
+                    (phase == ACQ)
+                    & jnp.where(in_cur_group, s["granted"], True).all(axis=1)
+                )
+                nxt_cc = jnp.where(
+                    (kk[None, :] >= ccptr[:, None]) & kvalid & ~in_cur_group,
+                    kk[None, :],
+                    K,
+                ).min(axis=1)
+                more = grp_done & (nxt_cc < K)
+                ccptr = jnp.where(more, nxt_cc, ccptr)
+                s["adm_done"] = jnp.where(more[:, None], False, s["adm_done"])
+                phase = jnp.where(grp_done, MSG, phase)
+                msg_stage = jnp.where(grp_done, jnp.where(more, 0, 1), msg_stage)
+                msg_arrive = jnp.where(
+                    grp_done, r + cm.msg_hop_rounds, msg_arrive
+                )
+                # response arrives -> READY
+                resp = (
+                    (phase == MSG) & (msg_stage == 1) & (msg_arrive <= r)
+                )
+                phase = jnp.where(resp, READY, phase)
+                # exec-lane scheduling: oldest READY per idle lane starts
+                lane_busy = jax.ops.segment_sum(
+                    ((phase == EXEC) & ~free).astype(jnp.int32),
+                    lane_of,
                     num_segments=cfg.n_exec,
                 )
-                act_next = act_next | (
-                    (phase == READY) & (lane_exec_busy[lane_of] == 0)
+                ready = phase == READY
+                ready_ts = jnp.where(ready, ts, jnp.iinfo(jnp.int32).max)
+                lane_min = jax.ops.segment_min(
+                    ready_ts, lane_of, num_segments=cfg.n_exec
+                )
+                startx = (
+                    ready
+                    & (ready_ts == lane_min[lane_of])
+                    & (lane_busy[lane_of] == 0)
+                )
+                # break ties (same ts impossible — tids unique) -> safe
+                phase = jnp.where(startx, EXEC, phase)
+                busy_until = jnp.where(
+                    startx, r + execops * exec_rounds_one, busy_until
+                )
+                busy_kind = jnp.where(startx, CAT_EXEC, busy_kind)
+
+            # EXEC finished -> release (commit, or OLLP-miss abort+retry)
+            free = busy_until <= r
+            fin = (phase == EXEC) & free
+            is_miss = fin & miss & (attempt == 0)
+            s["aborts_ollp"] = s["aborts_ollp"] + is_miss.sum(dtype=jnp.int32)
+            s["wasted"] = s["wasted"] + jnp.where(is_miss, execops, 0).sum(
+                dtype=jnp.int32
+            )
+            phase = jnp.where(fin, REL, phase)
+            committing = jnp.where(fin, ~is_miss, committing)
+            rel_delay = cm.msg_hop_rounds if cfg.is_orthrus else 0
+            release_at = jnp.where(fin, r + rel_delay, release_at)
+            s["rel_done"] = jnp.where(fin[:, None], False, s["rel_done"])
+            s["want"] = s["want"] & ~fin[:, None]
+
+            # REL complete -> EMPTY (commit) or BACKOFF (retry). A slot leaves
+            # only after every lock it held has actually been released (the
+            # release scatter runs in stages 4/5 of a *subsequent* round).
+            rel_done_all = (
+                (phase == REL)
+                & (release_at <= r)
+                & ~(s["granted"]).any(axis=1)
+            )
+            com = rel_done_all & committing
+            s["commits"] = s["commits"] + com.sum(dtype=jnp.int32)
+            # metrics: commit-latency histogram (log-bucketed; bucket = count
+            # of powers of two <= latency). Commits only happen at executed
+            # rounds, so the scatter is bit-identical under event leaping.
+            lat = r - arrive
+            lat_b = jnp.sum(
+                lat[:, None] >= lat_pow2[None, :], axis=1, dtype=jnp.int32
+            )
+            s["lat_hist"] = s["lat_hist"].at[
+                jnp.where(com, lat_b, LAT_BUCKETS)
+            ].add(1, mode="drop")
+            aborting = rel_done_all & ~committing
+            if exp_backoff:
+                # bounded exponential backoff: base << attempt, shift-capped
+                # then clamped (deterministic integer math on C_ATTEMPT —
+                # cost_model.exp_backoff_rounds is the host oracle)
+                bo = jnp.minimum(
+                    cm.abort_backoff_rounds
+                    << jnp.minimum(attempt, BACKOFF_SHIFT_CAP),
+                    p["pol_bo_max"],
                 )
             else:
-                # an acquiring slot with no pending (un-granted) request
-                # places its next one immediately; a blocked waiter is
-                # woken by its holder's release timer
-                blocked = jnp.take_along_axis(
-                    s["want"] & ~s["granted"],
-                    jnp.minimum(kptr, K - 1)[:, None], axis=1
-                ).squeeze(1)
-                act_next = act_next | ((phase == ACQ) & free2 & ~blocked)
-            if dl in ("waitfor", "dreadlocks"):
-                # graph detectors evolve every waiting round (reach-matrix
-                # propagation + per-round spin debt): stay dense while any
-                # slot waits
-                act_next = act_next | waited.any()
-            cand = jnp.where(act_next, r + 1, cand)
-            nxt = jnp.clip(jnp.min(cand), r + 1, r_end)
-        else:
-            nxt = r + 1
-        leap = nxt - r
-        s["cat"] = s["cat"] + cat_counts * leap
-        s["steps"] = s["steps"] + 1
-        s["r"] = nxt
-        # metrics: queue samples at every grid point in (r, nxt]. The
-        # post-transition slot state persists unchanged through a leap
-        # gap and arrivals are closed-form in the round, so each grid
-        # point observes exactly what the dense loop would record.
-        qgrid = qgrid_pos * p["qgrid_iv"]
-        qm = (qgrid > r) & (qgrid <= nxt)
-        s["q_inflight"] = jnp.where(
-            qm, (tid >= 0).sum(dtype=i32), s["q_inflight"]
-        )
-        if open_arrival:
-            # backlog at grid point x: txns arrived by x (closed form —
-            # full workload cycles + whole epochs within the cycle,
-            # capped at N per cycle) minus the admission cursor; policy
-            # drops advance next_txn, so drops leave the backlog
-            arrived = arrived_by(qgrid)
-            s["q_depth"] = jnp.where(
-                qm, jnp.maximum(arrived - s["next_txn"], 0), s["q_depth"]
+                bo = cm.abort_backoff_rounds
+            if has_budget or policy == "deadline_shed":
+                # give-up paths: a retrying txn is dropped instead of backing
+                # off when its retry budget is spent (pol_sacrificed, checked
+                # first) or, under deadline_shed, when its end-to-end latency
+                # has already blown the deadline (pol_timedout)
+                give_up = jnp.zeros((T,), jnp.bool_)
+                if has_budget:
+                    sac = aborting & (attempt + 1 >= p["pol_retry_budget"])
+                    s["pol_sacrificed"] = (
+                        s["pol_sacrificed"] + sac.sum(dtype=i32)
+                    )
+                    give_up = give_up | sac
+                if policy == "deadline_shed":
+                    timed = (
+                        aborting & ~give_up
+                        & (r - arrive > p["pol_deadline"])
+                    )
+                    s["pol_timedout"] = (
+                        s["pol_timedout"] + timed.sum(dtype=i32)
+                    )
+                    give_up = give_up | timed
+                leave = committing | give_up
+                drop_tid = com | give_up
+                back = aborting & ~give_up
+            else:
+                leave = committing
+                drop_tid = com
+                back = aborting
+            if exp_backoff:
+                s["pol_backoff_rounds"] = s["pol_backoff_rounds"] + jnp.where(
+                    back, bo, 0
+                ).sum(dtype=i32)
+            phase = jnp.where(
+                rel_done_all, jnp.where(leave, EMPTY, BACKOFF), phase
             )
+            tid = jnp.where(drop_tid, -1, tid)
+            busy_until = jnp.where(back, r + bo, busy_until)
+            s["want"] = jnp.where(rel_done_all[:, None], False, s["want"])
+
+        # ------------------------------------------------ 11. lane accounting
+        with jax.named_scope("stage.accounting"):
+            busy = busy_until > r
+            slot_cat = jnp.where(
+                busy,
+                busy_kind,
+                jnp.where(
+                    (phase == ACQ) & (s["want"] & ~s["granted"]).any(axis=1),
+                    CAT_WAIT,
+                    jnp.where(
+                        (phase == MSG) | (phase == READY) | (phase == REL),
+                        CAT_MSG,
+                        CAT_IDLE,
+                    ),
+                ),
+            )
+            if cfg.is_orthrus:
+                # a lane is "exec" if its running slot is busy executing; else
+                # classify by the most advanced outstanding slot state
+                lane_exec = jax.ops.segment_max(
+                    (busy & (slot_cat == CAT_EXEC)).astype(jnp.int32), lane_of,
+                    num_segments=cfg.n_exec,
+                )
+                lane_wait = jax.ops.segment_max(
+                    (slot_cat == CAT_WAIT).astype(jnp.int32), lane_of,
+                    num_segments=cfg.n_exec,
+                )
+                lane_msg = jax.ops.segment_max(
+                    (slot_cat == CAT_MSG).astype(jnp.int32), lane_of,
+                    num_segments=cfg.n_exec,
+                )
+                lane_cat = jnp.where(
+                    lane_exec == 1,
+                    CAT_EXEC,
+                    jnp.where(lane_wait == 1, CAT_WAIT,
+                              jnp.where(lane_msg == 1, CAT_MSG, CAT_IDLE)),
+                )
+                cat_counts = jax.ops.segment_sum(
+                    jnp.ones((cfg.n_exec,), jnp.int32),
+                    lane_cat,
+                    num_segments=NCAT,
+                )
+            else:
+                cat_counts = jax.ops.segment_sum(
+                    jnp.ones((T,), jnp.int32), slot_cat, num_segments=NCAT
+                )
+
+        # ------------------------------------------------ 12. event leap
+        with jax.named_scope("stage.leap"):
+            # Advance straight to the next round at which any slot can act.
+            # Every skipped round is provably a no-op: every per-slot timer
+            # (busy_until / msg_arrive / release_at) lies beyond it and no slot
+            # is in a phase that acts unconditionally each round. Lane
+            # accounting is exact because the post-transition lane state (the
+            # `cat_counts` just computed) persists unchanged through the gap.
+            if cfg.event_leap:
+                busy2 = busy_until > r
+                free2 = ~busy2
+                # future per-slot timers; a busy expiry is always an event (it
+                # changes lane accounting even when no transition follows)
+                cand = jnp.where(busy2, busy_until, _IMAX)
+                # admission, release processing and message arrival ignore the
+                # busy timer (stages 1, 4, 5 have no `free` gate), so their
+                # timers and ready-to-act states are tracked unconditionally
+                cand = jnp.minimum(cand, jnp.where(
+                    (phase == MSG) & (msg_arrive > r), msg_arrive, _IMAX))
+                cand = jnp.minimum(cand, jnp.where(
+                    (phase == REL) & (release_at > r), release_at, _IMAX))
+                if lane_stream is None:
+                    if open_arrival:
+                        # the earliest admissible txn is global id next_txn
+                        # (post-admission); an empty slot acts once it has
+                        # arrived, and its arrival round is the wake-up
+                        # event until then (arrival is monotone in g, so no
+                        # admission can happen sooner)
+                        g0 = s["next_txn"]
+                        arr0 = arr_of(g0)
+                        if policy == "token_bucket":
+                            # admission additionally waits for token g0:
+                            # earliest grant round is the host oracle
+                            # cost_model.token_ready_round
+                            arr0 = jnp.maximum(arr0, _sat_mul(
+                                jnp.maximum(g0 - p["pol_tb_burst"] + 1, 0),
+                                p["pol_tb_iv"],
+                            ))
+                        can_adm = jnp.broadcast_to(arr0 <= r + 1, (T,))
+                        cand = jnp.minimum(cand, jnp.where(
+                            (phase == EMPTY).any(), arr0, _IMAX))
+                        # policy drop events are wake-ups in their own right
+                        # (not gated on an EMPTY slot): the next drop round
+                        # is closed-form in next_txn, so leaping lands on
+                        # it exactly and stage 1a stays dense-identical
+                        if policy == "bounded_backlog":
+                            cand = jnp.minimum(
+                                cand, arr_of(g0 + p["pol_cap"])
+                            )
+                        elif policy == "deadline_shed":
+                            cand = jnp.minimum(
+                                cand, arr0 + p["pol_deadline"] + 1
+                            )
+                    else:
+                        can_adm = jnp.ones((T,), jnp.bool_)
+                else:
+                    can_adm = (
+                        lane_stream[slot_ids, lane_ctr % meta.lane_cols] >= 0
+                    )
+                act_next = (
+                    ((phase == EMPTY) & can_adm)
+                    | ((phase == MSG) & (msg_arrive <= r))
+                    | ((phase == REL) & (release_at <= r))
+                    | (free2 & ((phase == INIT) | (phase == BACKOFF)))
+                )
+                if cfg.is_orthrus:
+                    # a READY slot starts the round its lane goes idle; while
+                    # the lane runs another slot, that slot's busy_until is the
+                    # wake-up event (already a candidate above)
+                    lane_exec_busy = jax.ops.segment_max(
+                        ((phase == EXEC) & busy2).astype(jnp.int32), lane_of,
+                        num_segments=cfg.n_exec,
+                    )
+                    act_next = act_next | (
+                        (phase == READY) & (lane_exec_busy[lane_of] == 0)
+                    )
+                else:
+                    # an acquiring slot with no pending (un-granted) request
+                    # places its next one immediately; a blocked waiter is
+                    # woken by its holder's release timer
+                    blocked = jnp.take_along_axis(
+                        s["want"] & ~s["granted"],
+                        jnp.minimum(kptr, K - 1)[:, None], axis=1
+                    ).squeeze(1)
+                    act_next = act_next | ((phase == ACQ) & free2 & ~blocked)
+                if dl in ("waitfor", "dreadlocks"):
+                    # graph detectors evolve every waiting round (reach-matrix
+                    # propagation + per-round spin debt): stay dense while any
+                    # slot waits
+                    act_next = act_next | waited.any()
+                cand = jnp.where(act_next, r + 1, cand)
+                nxt = jnp.clip(jnp.min(cand), r + 1, r_end)
+            else:
+                nxt = r + 1
+            leap = nxt - r
+            s["cat"] = s["cat"] + cat_counts * leap
+            s["steps"] = s["steps"] + 1
+            s["r"] = nxt
+            # metrics: queue samples at every grid point in (r, nxt]. The
+            # post-transition slot state persists unchanged through a leap
+            # gap and arrivals are closed-form in the round, so each grid
+            # point observes exactly what the dense loop would record.
+            qgrid = qgrid_pos * p["qgrid_iv"]
+            qm = (qgrid > r) & (qgrid <= nxt)
+            s["q_inflight"] = jnp.where(
+                qm, (tid >= 0).sum(dtype=i32), s["q_inflight"]
+            )
+            if open_arrival:
+                # backlog at grid point x: txns arrived by x (closed form —
+                # full workload cycles + whole epochs within the cycle,
+                # capped at N per cycle) minus the admission cursor; policy
+                # drops advance next_txn, so drops leave the backlog
+                arrived = arrived_by(qgrid)
+                s["q_depth"] = jnp.where(
+                    qm, jnp.maximum(arrived - s["next_txn"], 0), s["q_depth"]
+                )
         s["slots"] = jnp.stack(
             [tid, widx, lane_ctr, ts, phase, committing.astype(i32),
              busy_until, busy_kind, kptr, attempt, ccptr, msg_arrive,
@@ -2312,482 +2324,491 @@ def make_batch_step(cfg: EngineConfig, meta: PlanMeta):
         arrive = sl[BC_ARRIVE]
 
         # -------------------------------------------- 1. batch rollover
-        # When every transaction of the current batch has committed, open
-        # the next one. Planning models, in order of fidelity:
-        #   * default: pipelined latency — planners started on the next
-        #     batch the moment they finished this one, so the new
-        #     batch's plan-ready round advances by its own planning span;
-        #   * open arrival (epoch_interval_rounds > 0): same, but a plan
-        #     cannot start before its batch arrives (epoch g arrives at
-        #     round g * interval);
-        #   * planner-lane throughput model (n_planner_lanes = L > 0):
-        #     batch g is planned end-to-end by lane g % L; the plan
-        #     starts at max(arrival, lane free) and occupies the lane
-        #     for its conflict-graph-scaled work span, so high epoch
-        #     rates queue plans behind saturated lanes (the fig15
-        #     plateau). The schedule depends only on the arrival and
-        #     work sequences (cost_model.planner_lane_schedule is the
-        #     host-side oracle).
-        adv = s["batch_left"] == 0
-        if policy in ("bounded_backlog", "deadline_shed"):
-            # epoch-granular shedding: at rollover (always an executed
-            # round, so dense and leaped runs evaluate the same r) skip
-            # straight past the epochs the queue policy has dropped —
-            # those beyond the backlog cap (oldest first), or those
-            # whose queueing delay already exceeds the deadline. The
-            # dropped units advance next_txn so the backlog samples see
-            # them leave the queue.
-            g_next = s["epoch_ctr"] + 1
-            if policy == "bounded_backlog":
-                floor_g = epochs_arrived_by(r) - p["pol_cap_epochs"]
-            else:
-                floor_g = epochs_arrived_by(r - p["pol_deadline"] - 1)
-            skip = jnp.where(adv, jnp.clip(floor_g - g_next, 0, _SAT), 0)
-            dropped = units_before(g_next + skip) - units_before(g_next)
-            ckey = (
-                "pol_rejected" if policy == "bounded_backlog"
-                else "pol_shed"
-            )
-            s[ckey] = s[ckey] + dropped
-            s["next_txn"] = s["next_txn"] + dropped
-        else:
-            skip = 0
-        new_b = jnp.where(
-            adv, (s["cur_batch"] + 1 + skip) % NB, s["cur_batch"]
-        )
-        # stale flags (the workload wraps around modulo NB) are cleared
-        # one batch ahead of admission: the incoming batch here, or the
-        # incoming *pipeline* batch when early admission is on (the new
-        # current batch's flags were cleared at the previous rollover)
-        clr_b = (new_b + 1) % NB if pipe else new_b
-        s["done"] = jnp.where(adv & (unit_batch == clr_b), False, s["done"])
-        if frag:
-            s["txn_left"] = jnp.where(
-                adv & (batch_of == clr_b), p["txn_nfrags"], s["txn_left"]
-            )
-        if pipe:
-            # admission continues where the pipelined cursor stopped;
-            # commits that ran ahead of the barrier are already paid
-            s["bpos"] = jnp.where(adv, s["pbpos"], s["bpos"])
-            s["pbpos"] = jnp.where(adv, ustart[clr_b], s["pbpos"])
-            s["batch_left"] = jnp.where(
-                adv, bsize[new_b] - s["pipe_com"], s["batch_left"]
-            )
-            s["pipe_com"] = jnp.where(adv, 0, s["pipe_com"])
-        else:
-            s["bpos"] = jnp.where(adv, ustart[new_b], s["bpos"])
-            s["batch_left"] = jnp.where(adv, bsize[new_b], s["batch_left"])
-        if planner_model or open_arrival:
-            g_new = s["epoch_ctr"] + 1 + skip  # new batch's global index
-            if open_arrival:
-                arrive_new = ep_arrival(g_new)
-                if policy == "token_bucket":
-                    # backpressure: epoch g's plan additionally waits
-                    # for its (epoch-granular) token; the arrival stamp
-                    # below keeps the true arrival round, so latency
-                    # includes the token wait
-                    arrive_new = jnp.maximum(arrive_new, _sat_mul(
-                        jnp.maximum(
-                            g_new - p["pol_tb_burst_e"] + 1, 0
-                        ),
-                        p["pol_tb_iv"],
-                    ))
-            else:
-                arrive_new = g_new * interval
-        if planner_model:
-            lane = g_new % L
-            lane_free_prev = s["lane_free"][lane]
-            ready = jnp.maximum(arrive_new, lane_free_prev) + p[
-                "plan_work"][new_b]
-            s["plan_qdelay"] = s["plan_qdelay"] + jnp.where(
-                adv, jnp.maximum(lane_free_prev - arrive_new, 0), 0
-            )
-            s["plan_busy"] = s["plan_busy"] + jnp.where(
-                adv, p["plan_work"][new_b], 0
-            )
-            # Round-granular lane-busy integral. The schedule is
-            # evaluated lazily at rollover, so the new span
-            # [start_new, ready) may already be partly (or wholly) in
-            # the past: credit its elapsed part now — never its future
-            # part, which the per-step overlap accumulation (stage 8)
-            # picks up as rounds elapse, keeping the integral <= L * r
-            # at every instant (the fig15 >1.0-utilization fix). The
-            # replaced span's unelapsed remainder is parked in the
-            # pb_span carry; a carry overwritten while it still has a
-            # remainder undercounts, which needs a plan to outlive L
-            # subsequent batch executions (not observed in practice).
-            start_new = jnp.maximum(arrive_new, lane_free_prev)
-            elapsed_part = jnp.maximum(
-                jnp.minimum(ready, r) - start_new, 0
-            )
-            s["plan_busy_int"] = s["plan_busy_int"] + jnp.where(
-                adv, elapsed_part, 0
-            )
-            old_start = s["lane_start"][lane]
-            keep_old = adv & (lane_free_prev > r)
-            s["pb_span"] = jnp.where(
-                keep_old,
-                jnp.stack([jnp.maximum(old_start, r), lane_free_prev]),
-                s["pb_span"],
-            )
-            s["lane_start"] = s["lane_start"].at[lane].set(
-                jnp.where(adv, start_new, old_start)
-            )
-            s["lane_free"] = s["lane_free"].at[lane].set(
-                jnp.where(adv, ready, lane_free_prev)
-            )
-            new_plan_fin = ready
-        elif open_arrival:
-            new_plan_fin = (
-                jnp.maximum(arrive_new, s["plan_fin"]) + plan_rounds[new_b]
-            )
-        else:
-            new_plan_fin = s["plan_fin"] + plan_rounds[new_b]
-        s["plan_fin"] = jnp.where(adv, new_plan_fin, s["plan_fin"])
-        if planner_model or open_arrival:
-            s["epoch_ctr"] = s["epoch_ctr"] + adv.astype(jnp.int32) + skip
-        s["cur_batch"] = new_b
-
-        def next_plan_fin(nb):
-            # modeled plan-ready round of the *next* batch (global epoch
-            # epoch_ctr + 1): what the pipelined level-0 prefix waits
-            # for — the plan, not the batch barrier. Identical to the
-            # value the rollover above will commit for that batch
-            # (lane_free is only written at rollovers).
-            if planner_model:
-                g_nxt = s["epoch_ctr"] + 1
-                a_nxt = (
-                    ep_arrival(g_nxt) if open_arrival
-                    else g_nxt * interval
+        with jax.named_scope("stage.rollover"):
+            # When every transaction of the current batch has committed, open
+            # the next one. Planning models, in order of fidelity:
+            #   * default: pipelined latency — planners started on the next
+            #     batch the moment they finished this one, so the new
+            #     batch's plan-ready round advances by its own planning span;
+            #   * open arrival (epoch_interval_rounds > 0): same, but a plan
+            #     cannot start before its batch arrives (epoch g arrives at
+            #     round g * interval);
+            #   * planner-lane throughput model (n_planner_lanes = L > 0):
+            #     batch g is planned end-to-end by lane g % L; the plan
+            #     starts at max(arrival, lane free) and occupies the lane
+            #     for its conflict-graph-scaled work span, so high epoch
+            #     rates queue plans behind saturated lanes (the fig15
+            #     plateau). The schedule depends only on the arrival and
+            #     work sequences (cost_model.planner_lane_schedule is the
+            #     host-side oracle).
+            adv = s["batch_left"] == 0
+            if policy in ("bounded_backlog", "deadline_shed"):
+                # epoch-granular shedding: at rollover (always an executed
+                # round, so dense and leaped runs evaluate the same r) skip
+                # straight past the epochs the queue policy has dropped —
+                # those beyond the backlog cap (oldest first), or those
+                # whose queueing delay already exceeds the deadline. The
+                # dropped units advance next_txn so the backlog samples see
+                # them leave the queue.
+                g_next = s["epoch_ctr"] + 1
+                if policy == "bounded_backlog":
+                    floor_g = epochs_arrived_by(r) - p["pol_cap_epochs"]
+                else:
+                    floor_g = epochs_arrived_by(r - p["pol_deadline"] - 1)
+                skip = jnp.where(adv, jnp.clip(floor_g - g_next, 0, _SAT), 0)
+                dropped = units_before(g_next + skip) - units_before(g_next)
+                ckey = (
+                    "pol_rejected" if policy == "bounded_backlog"
+                    else "pol_shed"
                 )
-                return jnp.maximum(
-                    a_nxt, s["lane_free"][g_nxt % L]
-                ) + p["plan_work"][nb]
-            if open_arrival:
-                return jnp.maximum(
-                    ep_arrival(s["epoch_ctr"] + 1), s["plan_fin"]
-                ) + plan_rounds[nb]
-            return s["plan_fin"] + plan_rounds[nb]
+                s[ckey] = s[ckey] + dropped
+                s["next_txn"] = s["next_txn"] + dropped
+            else:
+                skip = 0
+            new_b = jnp.where(
+                adv, (s["cur_batch"] + 1 + skip) % NB, s["cur_batch"]
+            )
+            # stale flags (the workload wraps around modulo NB) are cleared
+            # one batch ahead of admission: the incoming batch here, or the
+            # incoming *pipeline* batch when early admission is on (the new
+            # current batch's flags were cleared at the previous rollover)
+            clr_b = (new_b + 1) % NB if pipe else new_b
+            s["done"] = jnp.where(adv & (unit_batch == clr_b), False, s["done"])
+            if frag:
+                s["txn_left"] = jnp.where(
+                    adv & (batch_of == clr_b), p["txn_nfrags"], s["txn_left"]
+                )
+            if pipe:
+                # admission continues where the pipelined cursor stopped;
+                # commits that ran ahead of the barrier are already paid
+                s["bpos"] = jnp.where(adv, s["pbpos"], s["bpos"])
+                s["pbpos"] = jnp.where(adv, ustart[clr_b], s["pbpos"])
+                s["batch_left"] = jnp.where(
+                    adv, bsize[new_b] - s["pipe_com"], s["batch_left"]
+                )
+                s["pipe_com"] = jnp.where(adv, 0, s["pipe_com"])
+            else:
+                s["bpos"] = jnp.where(adv, ustart[new_b], s["bpos"])
+                s["batch_left"] = jnp.where(adv, bsize[new_b], s["batch_left"])
+            if planner_model or open_arrival:
+                g_new = s["epoch_ctr"] + 1 + skip  # new batch's global index
+                if open_arrival:
+                    arrive_new = ep_arrival(g_new)
+                    if policy == "token_bucket":
+                        # backpressure: epoch g's plan additionally waits
+                        # for its (epoch-granular) token; the arrival stamp
+                        # below keeps the true arrival round, so latency
+                        # includes the token wait
+                        arrive_new = jnp.maximum(arrive_new, _sat_mul(
+                            jnp.maximum(
+                                g_new - p["pol_tb_burst_e"] + 1, 0
+                            ),
+                            p["pol_tb_iv"],
+                        ))
+                else:
+                    arrive_new = g_new * interval
+            if planner_model:
+                lane = g_new % L
+                lane_free_prev = s["lane_free"][lane]
+                ready = jnp.maximum(arrive_new, lane_free_prev) + p[
+                    "plan_work"][new_b]
+                s["plan_qdelay"] = s["plan_qdelay"] + jnp.where(
+                    adv, jnp.maximum(lane_free_prev - arrive_new, 0), 0
+                )
+                s["plan_busy"] = s["plan_busy"] + jnp.where(
+                    adv, p["plan_work"][new_b], 0
+                )
+                # Round-granular lane-busy integral. The schedule is
+                # evaluated lazily at rollover, so the new span
+                # [start_new, ready) may already be partly (or wholly) in
+                # the past: credit its elapsed part now — never its future
+                # part, which the per-step overlap accumulation (stage 8)
+                # picks up as rounds elapse, keeping the integral <= L * r
+                # at every instant (the fig15 >1.0-utilization fix). The
+                # replaced span's unelapsed remainder is parked in the
+                # pb_span carry; a carry overwritten while it still has a
+                # remainder undercounts, which needs a plan to outlive L
+                # subsequent batch executions (not observed in practice).
+                start_new = jnp.maximum(arrive_new, lane_free_prev)
+                elapsed_part = jnp.maximum(
+                    jnp.minimum(ready, r) - start_new, 0
+                )
+                s["plan_busy_int"] = s["plan_busy_int"] + jnp.where(
+                    adv, elapsed_part, 0
+                )
+                old_start = s["lane_start"][lane]
+                keep_old = adv & (lane_free_prev > r)
+                s["pb_span"] = jnp.where(
+                    keep_old,
+                    jnp.stack([jnp.maximum(old_start, r), lane_free_prev]),
+                    s["pb_span"],
+                )
+                s["lane_start"] = s["lane_start"].at[lane].set(
+                    jnp.where(adv, start_new, old_start)
+                )
+                s["lane_free"] = s["lane_free"].at[lane].set(
+                    jnp.where(adv, ready, lane_free_prev)
+                )
+                new_plan_fin = ready
+            elif open_arrival:
+                new_plan_fin = (
+                    jnp.maximum(arrive_new, s["plan_fin"]) + plan_rounds[new_b]
+                )
+            else:
+                new_plan_fin = s["plan_fin"] + plan_rounds[new_b]
+            s["plan_fin"] = jnp.where(adv, new_plan_fin, s["plan_fin"])
+            if planner_model or open_arrival:
+                s["epoch_ctr"] = s["epoch_ctr"] + adv.astype(jnp.int32) + skip
+            s["cur_batch"] = new_b
+
+            def next_plan_fin(nb):
+                # modeled plan-ready round of the *next* batch (global epoch
+                # epoch_ctr + 1): what the pipelined level-0 prefix waits
+                # for — the plan, not the batch barrier. Identical to the
+                # value the rollover above will commit for that batch
+                # (lane_free is only written at rollovers).
+                if planner_model:
+                    g_nxt = s["epoch_ctr"] + 1
+                    a_nxt = (
+                        ep_arrival(g_nxt) if open_arrival
+                        else g_nxt * interval
+                    )
+                    return jnp.maximum(
+                        a_nxt, s["lane_free"][g_nxt % L]
+                    ) + p["plan_work"][nb]
+                if open_arrival:
+                    return jnp.maximum(
+                        ep_arrival(s["epoch_ctr"] + 1), s["plan_fin"]
+                    ) + plan_rounds[nb]
+                return s["plan_fin"] + plan_rounds[nb]
 
         # -------------------------------------------- 2. admission
-        # Empty slots pull the next positions of the current batch, in
-        # the planner's serial order, once the batch's plan is ready.
-        # Unit positions index transactions (txn mode) or fragments in
-        # admission order (fragment mode).
-        empty = phase == EMPTY
-        rank = jnp.cumsum(empty.astype(jnp.int32)) - 1
-        pos = s["bpos"] + rank
-        bend = ustart[s["cur_batch"]] + usize[s["cur_batch"]]
-        if pipe:
-            # ranks beyond the current batch's remaining units spill into
-            # the next batch's level-0 fragment prefix (its plan finishes
-            # one planning span after the current one's)
-            cur_avail = jnp.maximum(bend - s["bpos"], 0)
-            adm_cur = empty & (rank < cur_avail) & (r >= s["plan_fin"])
-            nb = (s["cur_batch"] + 1) % NB
-            nlvl_end = ustart[nb] + p["lvl0_fcount"][nb]
-            plan_fin_next = next_plan_fin(nb)
-            ppos = s["pbpos"] + (rank - cur_avail)
-            adm_pipe = (
-                empty
-                & (rank >= cur_avail)
-                & (ppos < nlvl_end)
-                & (r >= plan_fin_next)
-            )
-            adm = adm_cur | adm_pipe
-            upos = jnp.where(adm_pipe, ppos, pos)
-            s["bpos"] = s["bpos"] + adm_cur.sum(dtype=jnp.int32)
-            n_pipe = adm_pipe.sum(dtype=jnp.int32)
-            s["pbpos"] = s["pbpos"] + n_pipe
-            s["pipe_adm"] = s["pipe_adm"] + n_pipe
-            n_adm = adm.sum(dtype=jnp.int32)
-        else:
-            adm = empty & (pos < bend) & (r >= s["plan_fin"])
-            upos = pos
-            n_adm = adm.sum(dtype=jnp.int32)
-            s["bpos"] = s["bpos"] + n_adm
-        widx = jnp.where(adm, upos, widx)
-        new_tid = s["next_txn"] + rank
-        tid = jnp.where(adm, new_tid, tid)
-        ts = jnp.where(adm, new_tid, ts)
-        # metrics: stamp the unit's arrival round — its epoch's arrival
-        # under open arrival (pipelined early admissions belong to the
-        # *next* epoch), the admission round under closed loop
-        if open_arrival:
-            arr_cur = ep_arrival(s["epoch_ctr"])
+        with jax.named_scope("stage.admit"):
+            # Empty slots pull the next positions of the current batch, in
+            # the planner's serial order, once the batch's plan is ready.
+            # Unit positions index transactions (txn mode) or fragments in
+            # admission order (fragment mode).
+            empty = phase == EMPTY
+            rank = jnp.cumsum(empty.astype(jnp.int32)) - 1
+            pos = s["bpos"] + rank
+            bend = ustart[s["cur_batch"]] + usize[s["cur_batch"]]
             if pipe:
-                arr_new = jnp.where(
-                    adm_pipe, ep_arrival(s["epoch_ctr"] + 1), arr_cur
+                # ranks beyond the current batch's remaining units spill into
+                # the next batch's level-0 fragment prefix (its plan finishes
+                # one planning span after the current one's)
+                cur_avail = jnp.maximum(bend - s["bpos"], 0)
+                adm_cur = empty & (rank < cur_avail) & (r >= s["plan_fin"])
+                nb = (s["cur_batch"] + 1) % NB
+                nlvl_end = ustart[nb] + p["lvl0_fcount"][nb]
+                plan_fin_next = next_plan_fin(nb)
+                ppos = s["pbpos"] + (rank - cur_avail)
+                adm_pipe = (
+                    empty
+                    & (rank >= cur_avail)
+                    & (ppos < nlvl_end)
+                    & (r >= plan_fin_next)
+                )
+                adm = adm_cur | adm_pipe
+                upos = jnp.where(adm_pipe, ppos, pos)
+                s["bpos"] = s["bpos"] + adm_cur.sum(dtype=jnp.int32)
+                n_pipe = adm_pipe.sum(dtype=jnp.int32)
+                s["pbpos"] = s["pbpos"] + n_pipe
+                s["pipe_adm"] = s["pipe_adm"] + n_pipe
+                n_adm = adm.sum(dtype=jnp.int32)
+            else:
+                adm = empty & (pos < bend) & (r >= s["plan_fin"])
+                upos = pos
+                n_adm = adm.sum(dtype=jnp.int32)
+                s["bpos"] = s["bpos"] + n_adm
+            widx = jnp.where(adm, upos, widx)
+            new_tid = s["next_txn"] + rank
+            tid = jnp.where(adm, new_tid, tid)
+            ts = jnp.where(adm, new_tid, ts)
+            # metrics: stamp the unit's arrival round — its epoch's arrival
+            # under open arrival (pipelined early admissions belong to the
+            # *next* epoch), the admission round under closed loop
+            if open_arrival:
+                arr_cur = ep_arrival(s["epoch_ctr"])
+                if pipe:
+                    arr_new = jnp.where(
+                        adm_pipe, ep_arrival(s["epoch_ctr"] + 1), arr_cur
+                    )
+                else:
+                    arr_new = arr_cur
+                arrive = jnp.where(adm, arr_new, arrive)
+            else:
+                arrive = jnp.where(adm, r, arrive)
+            s["next_txn"] = s["next_txn"] + n_adm
+            if policy == "token_bucket":
+                s["pol_tb_adm"] = s["pol_tb_adm"] + n_adm
+            if frag:
+                ftxn = jnp.where(
+                    adm, p["frag_txn"][jnp.clip(widx, 0, F - 1)], ftxn
                 )
             else:
-                arr_new = arr_cur
-            arrive = jnp.where(adm, arr_new, arrive)
-        else:
-            arrive = jnp.where(adm, r, arrive)
-        s["next_txn"] = s["next_txn"] + n_adm
-        if policy == "token_bucket":
-            s["pol_tb_adm"] = s["pol_tb_adm"] + n_adm
-        if frag:
-            ftxn = jnp.where(
-                adm, p["frag_txn"][jnp.clip(widx, 0, F - 1)], ftxn
+                ftxn = jnp.where(adm, widx, ftxn)
+            # one fused [T, 2] gather: (npred, exec_ops); widx is fixed for
+            # the rest of the round, so the predecessor rows gathered here
+            # serve both the wavefront check and the event leap
+            ne = ne_all[widx]
+            npred_t = ne[:, 0]
+            exec_t = ne[:, 1]
+            preds = pred_pad[widx]  # [T, P]
+            init_busy = rounds_of(
+                cm.txn_fixed_cycles + npred_t * cm.dep_check_cycles
             )
-        else:
-            ftxn = jnp.where(adm, widx, ftxn)
-        # one fused [T, 2] gather: (npred, exec_ops); widx is fixed for
-        # the rest of the round, so the predecessor rows gathered here
-        # serve both the wavefront check and the event leap
-        ne = ne_all[widx]
-        npred_t = ne[:, 0]
-        exec_t = ne[:, 1]
-        preds = pred_pad[widx]  # [T, P]
-        init_busy = rounds_of(
-            cm.txn_fixed_cycles + npred_t * cm.dep_check_cycles
-        )
-        phase = jnp.where(adm, INIT, phase)
-        busy_until = jnp.where(adm, r + init_busy, busy_until)
-        busy_kind = jnp.where(adm, CAT_LOCK, busy_kind)
+            phase = jnp.where(adm, INIT, phase)
+            busy_until = jnp.where(adm, r + init_busy, busy_until)
+            busy_kind = jnp.where(adm, CAT_LOCK, busy_kind)
 
         # -------------------------------------------- 3. INIT -> MSG
-        # The exec lane fetches its next planned entry from the scheduler
-        # queue: one SPSC hop (functional separation, as in ORTHRUS).
-        free = busy_until <= r
-        start = (phase == INIT) & free & (tid >= 0)
-        phase = jnp.where(start, MSG, phase)
-        msg_arrive = jnp.where(start, r + cm.msg_hop_rounds, msg_arrive)
-        got = (phase == MSG) & (msg_arrive <= r)
-        phase = jnp.where(got, READY, phase)
+        with jax.named_scope("stage.msg"):
+            # The exec lane fetches its next planned entry from the scheduler
+            # queue: one SPSC hop (functional separation, as in ORTHRUS).
+            free = busy_until <= r
+            start = (phase == INIT) & free & (tid >= 0)
+            phase = jnp.where(start, MSG, phase)
+            msg_arrive = jnp.where(start, r + cm.msg_hop_rounds, msg_arrive)
+            got = (phase == MSG) & (msg_arrive <= r)
+            phase = jnp.where(got, READY, phase)
 
         # -------------------------------------------- 4. wavefront check
-        # "All planned predecessors committed" — the dep_wavefront
-        # primitive, either in dense per-slot form or as the Pallas
-        # segmented edge scan over the loaded slots' rows
-        # (fragment-granular when cfg.fragment_exec: preds are
-        # fragments, done is [F]).
-        if use_pallas:
-            # one edge per (slot, pred) pair; stale slots contribute
-            # duplicate copies of real rows (or sentinel padding),
-            # which cannot change any unit's readiness
-            edge_dst = jnp.where(
-                preds >= 0,
-                jnp.broadcast_to(widx[:, None], preds.shape),
-                KEY_SENTINEL,
-            ).reshape(-1)
-            edge_src = jnp.maximum(preds, 0).reshape(-1)
-            ready_u = _dep_ready(
-                edge_dst, edge_src, s["done"], num_txns=NU,
-                block_n=wave_block,
-            )
-            dep_ok = ready_u[widx]
-        else:
-            pred_ok = (preds < 0) | s["done"][jnp.maximum(preds, 0)]
-            dep_ok = pred_ok.all(axis=1)
-        ready = (phase == READY) & dep_ok
+        with jax.named_scope("stage.wavefront"):
+            # "All planned predecessors committed" — the dep_wavefront
+            # primitive, either in dense per-slot form or as the Pallas
+            # segmented edge scan over the loaded slots' rows
+            # (fragment-granular when cfg.fragment_exec: preds are
+            # fragments, done is [F]).
+            with jax.named_scope("kernel.dep_wavefront"):
+                if use_pallas:
+                    # one edge per (slot, pred) pair; stale slots contribute
+                    # duplicate copies of real rows (or sentinel padding),
+                    # which cannot change any unit's readiness
+                    edge_dst = jnp.where(
+                        preds >= 0,
+                        jnp.broadcast_to(widx[:, None], preds.shape),
+                        KEY_SENTINEL,
+                    ).reshape(-1)
+                    edge_src = jnp.maximum(preds, 0).reshape(-1)
+                    ready_u = _dep_ready(
+                        edge_dst, edge_src, s["done"], num_txns=NU,
+                        block_n=wave_block,
+                    )
+                    dep_ok = ready_u[widx]
+                else:
+                    pred_ok = (preds < 0) | s["done"][jnp.maximum(preds, 0)]
+                    dep_ok = pred_ok.all(axis=1)
+            ready = (phase == READY) & dep_ok
 
         # -------------------------------------------- 5. lane scheduling
-        busy = busy_until > r
-        lane_busy = jax.ops.segment_sum(
-            ((phase == EXEC) & busy).astype(jnp.int32),
-            lane_of,
-            num_segments=cfg.n_exec,
-        )
-        ready_ts = jnp.where(ready, ts, imax)
-        lane_min = jax.ops.segment_min(
-            ready_ts, lane_of, num_segments=cfg.n_exec
-        )
-        startx = (
-            ready
-            & (ready_ts == lane_min[lane_of])
-            & (lane_busy[lane_of] == 0)
-        )
-        phase = jnp.where(startx, EXEC, phase)
-        busy_until = jnp.where(
-            startx, r + exec_t * exec_rounds_one, busy_until
-        )
-        busy_kind = jnp.where(startx, CAT_EXEC, busy_kind)
-
-        # -------------------------------------------- 6. commit
-        # No locks to release and no abort path: planned execution is
-        # conflict-free by construction. In fragment mode a finished
-        # fragment marks itself done and decrements its transaction's
-        # outstanding-fragment count; the txn commits (once) when the
-        # count hits zero — the commit-when-all-fragments-done join.
-        free = busy_until <= r
-        fin = (phase == EXEC) & free
-        s["done"] = s["done"].at[jnp.where(fin, widx, NU)].set(
-            True, mode="drop"
-        )
-        if frag:
-            tl = s["txn_left"].at[jnp.where(fin, ftxn, N)].add(
-                -1, mode="drop"
-            )
-            s["txn_left"] = tl
-            tl_t = tl[jnp.where(fin, ftxn, 0)]
-            com_slot = fin & (tl_t == 0)
-            # several fragments of one txn can finish in the same round
-            # on different slots: only the lowest such slot commits it
-            same = (ftxn[None, :] == ftxn[:, None]) & com_slot[None, :]
-            com_first = slot_ids == jnp.min(
-                jnp.where(same, slot_ids[None, :], T), axis=1
-            )
-            com = com_slot & com_first
-            ncom = com.sum(dtype=jnp.int32)
-            if pipe:
-                com_b = batch_of[jnp.where(com, ftxn, 0)]
-                ncom_ahead = (com & (com_b != s["cur_batch"])).sum(
-                    dtype=jnp.int32
-                )
-                s["pipe_com"] = s["pipe_com"] + ncom_ahead
-                s["pipe_commits"] = s["pipe_commits"] + ncom_ahead
-                s["batch_left"] = s["batch_left"] - (ncom - ncom_ahead)
-            else:
-                s["batch_left"] = s["batch_left"] - ncom
-        else:
-            ncom = fin.sum(dtype=jnp.int32)
-            s["batch_left"] = s["batch_left"] - ncom
-        s["commits"] = s["commits"] + ncom
-        # metrics: commit-latency histogram (see make_step). In fragment
-        # mode the committing slot is the one whose fragment completed
-        # the txn, so its latency spans arrival -> last-fragment-done.
-        com_mask = com if frag else fin
-        lat = r - arrive
-        lat_b = jnp.sum(
-            lat[:, None] >= lat_pow2[None, :], axis=1, dtype=jnp.int32
-        )
-        s["lat_hist"] = s["lat_hist"].at[
-            jnp.where(com_mask, lat_b, LAT_BUCKETS)
-        ].add(1, mode="drop")
-        phase = jnp.where(fin, EMPTY, phase)
-        tid = jnp.where(fin, -1, tid)
-
-        # -------------------------------------------- 7. lane accounting
-        busy2 = busy_until > r
-        slot_cat = jnp.where(
-            busy2,
-            busy_kind,
-            jnp.where(
-                phase == MSG,
-                CAT_MSG,
-                jnp.where(phase == READY, CAT_WAIT, CAT_IDLE),
-            ),
-        )
-        lane_exec = jax.ops.segment_max(
-            (busy2 & (slot_cat == CAT_EXEC)).astype(jnp.int32), lane_of,
-            num_segments=cfg.n_exec,
-        )
-        lane_wait = jax.ops.segment_max(
-            (slot_cat == CAT_WAIT).astype(jnp.int32), lane_of,
-            num_segments=cfg.n_exec,
-        )
-        lane_msg = jax.ops.segment_max(
-            (slot_cat == CAT_MSG).astype(jnp.int32), lane_of,
-            num_segments=cfg.n_exec,
-        )
-        lane_cat = jnp.where(
-            lane_exec == 1,
-            CAT_EXEC,
-            jnp.where(lane_wait == 1, CAT_WAIT,
-                      jnp.where(lane_msg == 1, CAT_MSG, CAT_IDLE)),
-        )
-        cat_counts = jax.ops.segment_sum(
-            jnp.ones((cfg.n_exec,), jnp.int32),
-            lane_cat,
-            num_segments=NCAT,
-        )
-
-        # -------------------------------------------- 8. event leap
-        # Timers: busy_until (init dep-check spans, exec, pred commits),
-        # msg_arrive, and the scalar admission gate (plan_fin / batch
-        # rollover). A dep-blocked READY slot is woken by its predecessor's
-        # commit (the pred's busy_until); a dep-clear READY slot starts the
-        # round its lane goes idle.
-        if cfg.event_leap:
-            busy3 = busy_until > r
-            free3 = ~busy3
-            cand = jnp.where(busy3, busy_until, imax)
-            cand = jnp.minimum(cand, jnp.where(
-                (phase == MSG) & (msg_arrive > r), msg_arrive, imax))
-            act_next = (
-                (free3 & (phase == INIT))
-                | ((phase == MSG) & (msg_arrive <= r))
-            )
-            # same pred rows as stage 4 (widx unchanged); `done` moved, so
-            # the commit flags are re-gathered
-            pred_ok2 = (preds < 0) | s["done"][jnp.maximum(preds, 0)]
-            dep_ok2 = pred_ok2.all(axis=1)
-            lane_exec_busy = jax.ops.segment_max(
-                ((phase == EXEC) & busy3).astype(jnp.int32), lane_of,
+        with jax.named_scope("stage.schedule"):
+            busy = busy_until > r
+            lane_busy = jax.ops.segment_sum(
+                ((phase == EXEC) & busy).astype(jnp.int32),
+                lane_of,
                 num_segments=cfg.n_exec,
             )
-            act_next = act_next | (
-                (phase == READY) & dep_ok2 & (lane_exec_busy[lane_of] == 0)
+            ready_ts = jnp.where(ready, ts, imax)
+            lane_min = jax.ops.segment_min(
+                ready_ts, lane_of, num_segments=cfg.n_exec
             )
-            cand = jnp.where(act_next, r + 1, cand)
-            # admission is a scalar event: the next batch opens the round
-            # after batch_left hits zero; within a batch, empty slots admit
-            # once plan_fin has passed and positions remain
-            bend2 = ustart[s["cur_batch"]] + usize[s["cur_batch"]]
-            adm_evt = jnp.where(
-                s["batch_left"] == 0,
-                r + 1,
+            startx = (
+                ready
+                & (ready_ts == lane_min[lane_of])
+                & (lane_busy[lane_of] == 0)
+            )
+            phase = jnp.where(startx, EXEC, phase)
+            busy_until = jnp.where(
+                startx, r + exec_t * exec_rounds_one, busy_until
+            )
+            busy_kind = jnp.where(startx, CAT_EXEC, busy_kind)
+
+        # -------------------------------------------- 6. commit
+        with jax.named_scope("stage.commit"):
+            # No locks to release and no abort path: planned execution is
+            # conflict-free by construction. In fragment mode a finished
+            # fragment marks itself done and decrements its transaction's
+            # outstanding-fragment count; the txn commits (once) when the
+            # count hits zero — the commit-when-all-fragments-done join.
+            free = busy_until <= r
+            fin = (phase == EXEC) & free
+            s["done"] = s["done"].at[jnp.where(fin, widx, NU)].set(
+                True, mode="drop"
+            )
+            if frag:
+                tl = s["txn_left"].at[jnp.where(fin, ftxn, N)].add(
+                    -1, mode="drop"
+                )
+                s["txn_left"] = tl
+                tl_t = tl[jnp.where(fin, ftxn, 0)]
+                com_slot = fin & (tl_t == 0)
+                # several fragments of one txn can finish in the same round
+                # on different slots: only the lowest such slot commits it
+                same = (ftxn[None, :] == ftxn[:, None]) & com_slot[None, :]
+                com_first = slot_ids == jnp.min(
+                    jnp.where(same, slot_ids[None, :], T), axis=1
+                )
+                com = com_slot & com_first
+                ncom = com.sum(dtype=jnp.int32)
+                if pipe:
+                    com_b = batch_of[jnp.where(com, ftxn, 0)]
+                    ncom_ahead = (com & (com_b != s["cur_batch"])).sum(
+                        dtype=jnp.int32
+                    )
+                    s["pipe_com"] = s["pipe_com"] + ncom_ahead
+                    s["pipe_commits"] = s["pipe_commits"] + ncom_ahead
+                    s["batch_left"] = s["batch_left"] - (ncom - ncom_ahead)
+                else:
+                    s["batch_left"] = s["batch_left"] - ncom
+            else:
+                ncom = fin.sum(dtype=jnp.int32)
+                s["batch_left"] = s["batch_left"] - ncom
+            s["commits"] = s["commits"] + ncom
+            # metrics: commit-latency histogram (see make_step). In fragment
+            # mode the committing slot is the one whose fragment completed
+            # the txn, so its latency spans arrival -> last-fragment-done.
+            com_mask = com if frag else fin
+            lat = r - arrive
+            lat_b = jnp.sum(
+                lat[:, None] >= lat_pow2[None, :], axis=1, dtype=jnp.int32
+            )
+            s["lat_hist"] = s["lat_hist"].at[
+                jnp.where(com_mask, lat_b, LAT_BUCKETS)
+            ].add(1, mode="drop")
+            phase = jnp.where(fin, EMPTY, phase)
+            tid = jnp.where(fin, -1, tid)
+
+        # -------------------------------------------- 7. lane accounting
+        with jax.named_scope("stage.accounting"):
+            busy2 = busy_until > r
+            slot_cat = jnp.where(
+                busy2,
+                busy_kind,
                 jnp.where(
-                    s["bpos"] < bend2,
-                    jnp.maximum(s["plan_fin"], r + 1),
-                    imax,
+                    phase == MSG,
+                    CAT_MSG,
+                    jnp.where(phase == READY, CAT_WAIT, CAT_IDLE),
                 ),
             )
-            if pipe:
-                # pipelined admission wakes when the next batch's plan
-                # lands, while level-0 fragment positions remain
-                nb2 = (s["cur_batch"] + 1) % NB
-                nlvl_end2 = ustart[nb2] + p["lvl0_fcount"][nb2]
-                pipe_evt = jnp.where(
-                    s["pbpos"] < nlvl_end2,
-                    jnp.maximum(next_plan_fin(nb2), r + 1),
-                    imax,
+            lane_exec = jax.ops.segment_max(
+                (busy2 & (slot_cat == CAT_EXEC)).astype(jnp.int32), lane_of,
+                num_segments=cfg.n_exec,
+            )
+            lane_wait = jax.ops.segment_max(
+                (slot_cat == CAT_WAIT).astype(jnp.int32), lane_of,
+                num_segments=cfg.n_exec,
+            )
+            lane_msg = jax.ops.segment_max(
+                (slot_cat == CAT_MSG).astype(jnp.int32), lane_of,
+                num_segments=cfg.n_exec,
+            )
+            lane_cat = jnp.where(
+                lane_exec == 1,
+                CAT_EXEC,
+                jnp.where(lane_wait == 1, CAT_WAIT,
+                          jnp.where(lane_msg == 1, CAT_MSG, CAT_IDLE)),
+            )
+            cat_counts = jax.ops.segment_sum(
+                jnp.ones((cfg.n_exec,), jnp.int32),
+                lane_cat,
+                num_segments=NCAT,
+            )
+
+        # -------------------------------------------- 8. event leap
+        with jax.named_scope("stage.leap"):
+            # Timers: busy_until (init dep-check spans, exec, pred commits),
+            # msg_arrive, and the scalar admission gate (plan_fin / batch
+            # rollover). A dep-blocked READY slot is woken by its predecessor's
+            # commit (the pred's busy_until); a dep-clear READY slot starts the
+            # round its lane goes idle.
+            if cfg.event_leap:
+                busy3 = busy_until > r
+                free3 = ~busy3
+                cand = jnp.where(busy3, busy_until, imax)
+                cand = jnp.minimum(cand, jnp.where(
+                    (phase == MSG) & (msg_arrive > r), msg_arrive, imax))
+                act_next = (
+                    (free3 & (phase == INIT))
+                    | ((phase == MSG) & (msg_arrive <= r))
                 )
-                adm_evt = jnp.minimum(adm_evt, pipe_evt)
-            adm_evt = jnp.where((phase == EMPTY).any(), adm_evt, imax)
-            nxt = jnp.clip(jnp.minimum(jnp.min(cand), adm_evt), r + 1, r_end)
-        else:
-            nxt = r + 1
-        leap = nxt - r
-        s["cat"] = s["cat"] + cat_counts * leap
-        s["steps"] = s["steps"] + 1
-        s["r"] = nxt
-        if planner_model:
-            # round-granular planner-busy: overlap of each lane's live
-            # span (and the carry span) with the elapsed window [r, nxt)
-            # — spans only move at rollovers, which are always executed
-            # rounds, so the sum is bit-identical under event leaping
-            acc = jnp.maximum(
-                jnp.minimum(s["lane_free"], nxt)
-                - jnp.maximum(s["lane_start"], r),
-                0,
-            ).sum(dtype=i32)
-            acc = acc + jnp.maximum(
-                jnp.minimum(s["pb_span"][1], nxt)
-                - jnp.maximum(s["pb_span"][0], r),
-                0,
+                # same pred rows as stage 4 (widx unchanged); `done` moved, so
+                # the commit flags are re-gathered
+                pred_ok2 = (preds < 0) | s["done"][jnp.maximum(preds, 0)]
+                dep_ok2 = pred_ok2.all(axis=1)
+                lane_exec_busy = jax.ops.segment_max(
+                    ((phase == EXEC) & busy3).astype(jnp.int32), lane_of,
+                    num_segments=cfg.n_exec,
+                )
+                act_next = act_next | (
+                    (phase == READY) & dep_ok2 & (lane_exec_busy[lane_of] == 0)
+                )
+                cand = jnp.where(act_next, r + 1, cand)
+                # admission is a scalar event: the next batch opens the round
+                # after batch_left hits zero; within a batch, empty slots admit
+                # once plan_fin has passed and positions remain
+                bend2 = ustart[s["cur_batch"]] + usize[s["cur_batch"]]
+                adm_evt = jnp.where(
+                    s["batch_left"] == 0,
+                    r + 1,
+                    jnp.where(
+                        s["bpos"] < bend2,
+                        jnp.maximum(s["plan_fin"], r + 1),
+                        imax,
+                    ),
+                )
+                if pipe:
+                    # pipelined admission wakes when the next batch's plan
+                    # lands, while level-0 fragment positions remain
+                    nb2 = (s["cur_batch"] + 1) % NB
+                    nlvl_end2 = ustart[nb2] + p["lvl0_fcount"][nb2]
+                    pipe_evt = jnp.where(
+                        s["pbpos"] < nlvl_end2,
+                        jnp.maximum(next_plan_fin(nb2), r + 1),
+                        imax,
+                    )
+                    adm_evt = jnp.minimum(adm_evt, pipe_evt)
+                adm_evt = jnp.where((phase == EMPTY).any(), adm_evt, imax)
+                nxt = jnp.clip(jnp.minimum(jnp.min(cand), adm_evt), r + 1, r_end)
+            else:
+                nxt = r + 1
+            leap = nxt - r
+            s["cat"] = s["cat"] + cat_counts * leap
+            s["steps"] = s["steps"] + 1
+            s["r"] = nxt
+            if planner_model:
+                # round-granular planner-busy: overlap of each lane's live
+                # span (and the carry span) with the elapsed window [r, nxt)
+                # — spans only move at rollovers, which are always executed
+                # rounds, so the sum is bit-identical under event leaping
+                acc = jnp.maximum(
+                    jnp.minimum(s["lane_free"], nxt)
+                    - jnp.maximum(s["lane_start"], r),
+                    0,
+                ).sum(dtype=i32)
+                acc = acc + jnp.maximum(
+                    jnp.minimum(s["pb_span"][1], nxt)
+                    - jnp.maximum(s["pb_span"][0], r),
+                    0,
+                )
+                s["plan_busy_int"] = s["plan_busy_int"] + acc
+            # metrics: queue samples at every grid point in (r, nxt] (see
+            # make_step — post-transition state persists through the gap,
+            # and epoch arrivals are closed-form in the round)
+            qgrid = qgrid_pos * p["qgrid_iv"]
+            qm = (qgrid > r) & (qgrid <= nxt)
+            s["q_inflight"] = jnp.where(
+                qm, (tid >= 0).sum(dtype=i32), s["q_inflight"]
             )
-            s["plan_busy_int"] = s["plan_busy_int"] + acc
-        # metrics: queue samples at every grid point in (r, nxt] (see
-        # make_step — post-transition state persists through the gap,
-        # and epoch arrivals are closed-form in the round)
-        qgrid = qgrid_pos * p["qgrid_iv"]
-        qm = (qgrid > r) & (qgrid <= nxt)
-        s["q_inflight"] = jnp.where(
-            qm, (tid >= 0).sum(dtype=i32), s["q_inflight"]
-        )
-        if open_arrival:
-            # backlog in admission units (fragments under frag mode, to
-            # match next_txn's granularity): all units of the epochs
-            # arrived by grid point x, minus the admission cursor
-            # (policy drops advance the cursor, leaving the backlog)
-            n_arr = epochs_arrived_by(qgrid)
-            arrived = units_before(n_arr)
-            s["q_depth"] = jnp.where(
-                qm, jnp.maximum(arrived - s["next_txn"], 0), s["q_depth"]
-            )
+            if open_arrival:
+                # backlog in admission units (fragments under frag mode, to
+                # match next_txn's granularity): all units of the epochs
+                # arrived by grid point x, minus the admission cursor
+                # (policy drops advance the cursor, leaving the backlog)
+                n_arr = epochs_arrived_by(qgrid)
+                arrived = units_before(n_arr)
+                s["q_depth"] = jnp.where(
+                    qm, jnp.maximum(arrived - s["next_txn"], 0), s["q_depth"]
+                )
         s["slots"] = jnp.stack(
             [tid, widx, ts, phase, busy_until, busy_kind, msg_arrive, ftxn,
              arrive],
@@ -2826,34 +2847,35 @@ def _compact_keys(plan: planner_lib.Plan) -> planner_lib.Plan:
 
 def make_plan(cfg: EngineConfig, workload: Workload) -> planner_lib.Plan:
     """Plan the workload for the protocol (engine-ready arrays)."""
-    if cfg.protocol == "orthrus":
-        plan = planner_lib.plan_orthrus(workload, cfg.n_cc)
-    elif cfg.protocol == "deadlock_free":
-        plan = planner_lib.plan_sorted(workload)
-    elif cfg.protocol == "partitioned_store":
-        plan = planner_lib.plan_partition_store(workload, cfg.n_exec)
-    elif cfg.protocol == "dgcc":
-        plan = planner_lib.plan_dgcc(
-            workload, workload.cfg.batch_epoch,
-            n_lanes=max(cfg.n_cc, 1), fragments=cfg.fragment_exec,
-        )
-    elif cfg.protocol == "quecc":
-        plan = planner_lib.plan_quecc(
-            workload, max(cfg.n_cc, 1), workload.cfg.batch_epoch,
-            fragments=cfg.fragment_exec,
-        )
-    elif cfg.protocol == "scheduled":
-        # clusters round-robin over the *execution* lanes (there is no
-        # planner-lane key partition to inherit)
-        plan = planner_lib.plan_scheduled(
-            workload, workload.cfg.batch_epoch, n_lanes=max(cfg.n_exec, 1),
-        )
-    else:
-        plan = planner_lib.plan_dynamic(workload)
-    plan.epoch_txns = workload.cfg.batch_epoch  # open-arrival epoch size
-    if not cfg.is_batch_planned:
-        plan = _compact_keys(plan)
-    return plan
+    with jax.profiler.TraceAnnotation("repro.plan", protocol=cfg.protocol):
+        if cfg.protocol == "orthrus":
+            plan = planner_lib.plan_orthrus(workload, cfg.n_cc)
+        elif cfg.protocol == "deadlock_free":
+            plan = planner_lib.plan_sorted(workload)
+        elif cfg.protocol == "partitioned_store":
+            plan = planner_lib.plan_partition_store(workload, cfg.n_exec)
+        elif cfg.protocol == "dgcc":
+            plan = planner_lib.plan_dgcc(
+                workload, workload.cfg.batch_epoch,
+                n_lanes=max(cfg.n_cc, 1), fragments=cfg.fragment_exec,
+            )
+        elif cfg.protocol == "quecc":
+            plan = planner_lib.plan_quecc(
+                workload, max(cfg.n_cc, 1), workload.cfg.batch_epoch,
+                fragments=cfg.fragment_exec,
+            )
+        elif cfg.protocol == "scheduled":
+            # clusters round-robin over the *execution* lanes (there is no
+            # planner-lane key partition to inherit)
+            plan = planner_lib.plan_scheduled(
+                workload, workload.cfg.batch_epoch, n_lanes=max(cfg.n_exec, 1),
+            )
+        else:
+            plan = planner_lib.plan_dynamic(workload)
+        plan.epoch_txns = workload.cfg.batch_epoch  # open-arrival epoch size
+        if not cfg.is_batch_planned:
+            plan = _compact_keys(plan)
+        return plan
 
 
 def run_simulation(

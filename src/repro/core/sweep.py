@@ -94,7 +94,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from collections import OrderedDict, deque
 
 import jax
@@ -437,51 +436,53 @@ class _GroupRun:
         self.meta = next(iter(metas))
         self.cfgs, self.plans, self.mode, self.n = cfgs, plans, mode, n
 
-        if ps is None:
-            ps = [
-                engine_lib.plan_device(c, pl) for c, pl in zip(cfgs, plans)
-            ]
-        T = cfg0.n_slots
-        step_mod = _step_module(cfg0)
-        if cfg0.is_batch_planned:
-            states = [
-                step_mod._batch_state0(c, pl, T)
-                for c, pl in zip(cfgs, plans)
-            ]
-        else:
-            states = [
-                step_mod._state0(c, pl.num_records, T, self.meta.max_keys)
-                for c, pl in zip(cfgs, plans)
-            ]
+        with jax.profiler.TraceAnnotation("repro.upload"):
+            if ps is None:
+                ps = [
+                    engine_lib.plan_device(c, pl)
+                    for c, pl in zip(cfgs, plans)
+                ]
+            T = cfg0.n_slots
+            step_mod = _step_module(cfg0)
+            if cfg0.is_batch_planned:
+                states = [
+                    step_mod._batch_state0(c, pl, T)
+                    for c, pl in zip(cfgs, plans)
+                ]
+            else:
+                states = [
+                    step_mod._state0(c, pl.num_records, T, self.meta.max_keys)
+                    for c, pl in zip(cfgs, plans)
+                ]
 
-        # device layout: pad the cell axis to a multiple of the mesh
-        # size with duplicates of the last cell. Padded lanes are born
-        # frozen (r_end=0), so they cost one loop-condition check per
-        # chunk; their counters are never read.
-        d = max(1, min(mode.devices, jax.local_device_count(), n))
-        pad = (-n) % d
-        self.nb = nb = n + pad
-        self.batched = nb > 1
-        if pad:
-            ps = ps + [ps[-1]] * pad
-            states = states + [states[-1]] * pad
-        if self.batched:
-            p = {k: np.stack([q[k] for q in ps]) for k in ps[0]}
-            state = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *states
-            )
-        else:
-            p, state = ps[0], states[0]
-        if d > 1:
-            self.mesh = sharding_policies.cell_mesh(d)
-            shard = sharding_policies.cell_sharding
-            p = jax.device_put(p, shard(self.mesh, p))
-            state = jax.device_put(state, shard(self.mesh, state))
-        else:
-            self.mesh = None
-            # commit the (possibly numpy-stacked) plan once so chunks
-            # don't re-upload it every dispatch
-            p = jax.device_put(p)
+            # device layout: pad the cell axis to a multiple of the mesh
+            # size with duplicates of the last cell. Padded lanes are born
+            # frozen (r_end=0), so they cost one loop-condition check per
+            # chunk; their counters are never read.
+            d = max(1, min(mode.devices, jax.local_device_count(), n))
+            pad = (-n) % d
+            self.nb = nb = n + pad
+            self.batched = nb > 1
+            if pad:
+                ps = ps + [ps[-1]] * pad
+                states = states + [states[-1]] * pad
+            if self.batched:
+                p = {k: np.stack([q[k] for q in ps]) for k in ps[0]}
+                state = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *states
+                )
+            else:
+                p, state = ps[0], states[0]
+            if d > 1:
+                self.mesh = sharding_policies.cell_mesh(d)
+                shard = sharding_policies.cell_sharding
+                p = jax.device_put(p, shard(self.mesh, p))
+                state = jax.device_put(state, shard(self.mesh, state))
+            else:
+                self.mesh = None
+                # commit the (possibly numpy-stacked) plan once so chunks
+                # don't re-upload it every dispatch
+                p = jax.device_put(p)
         self.p, self.state = p, state
         self.runner = None  # compiled lazily at first dispatch
 
@@ -496,8 +497,7 @@ class _GroupRun:
         self.pending: deque = deque()
         self.stopped = False
         self.exhausted = False
-        self.t0: float | None = None
-        self.wall = 0.0
+        self.started = False
 
     def start(self) -> None:
         """Dispatch the first chunk (compiling the runner if needed).
@@ -505,8 +505,8 @@ class _GroupRun:
         :func:`run_cells` calls this on the *next* group while the
         current one executes, overlapping compile with execution.
         """
-        if self.t0 is None:
-            self.t0 = time.time()
+        if not self.started:
+            self.started = True
             self._dispatch_one()
 
     def _dispatch_one(self) -> bool:
@@ -516,30 +516,33 @@ class _GroupRun:
         if b is None:
             self.exhausted = True
             return False
-        if self.runner is None:
-            self.runner = get_runner(
-                self.cfgs[0], self.meta, batched=self.batched,
-                mesh=self.mesh,
-            )
-        if self.batched:
-            active = self.live if self.mode.early_exit else self._real
-            r_arg = jnp.asarray(
-                np.where(active, b, 0).astype(np.int32)
-            )
-            if self.mesh is not None:
-                r_arg = jax.device_put(
-                    r_arg,
-                    sharding_policies.cell_sharding(self.mesh, r_arg),
+        # the first dispatch of a runner key compiles inside this span
+        with jax.profiler.TraceAnnotation("repro.dispatch", round=b):
+            if self.runner is None:
+                self.runner = get_runner(
+                    self.cfgs[0], self.meta, batched=self.batched,
+                    mesh=self.mesh,
                 )
-        else:
-            r_arg = jnp.asarray(b, jnp.int32)
-        self.state = self.runner(self.p, self.state, r_arg)
-        self.pending.append((b, _snapshot_counters(self.state)))
+            if self.batched:
+                active = self.live if self.mode.early_exit else self._real
+                r_arg = jnp.asarray(
+                    np.where(active, b, 0).astype(np.int32)
+                )
+                if self.mesh is not None:
+                    r_arg = jax.device_put(
+                        r_arg,
+                        sharding_policies.cell_sharding(self.mesh, r_arg),
+                    )
+            else:
+                r_arg = jnp.asarray(b, jnp.int32)
+            self.state = self.runner(self.p, self.state, r_arg)
+            self.pending.append((b, _snapshot_counters(self.state)))
         return True
 
     def _resolve_one(self) -> None:
         b, snap = self.pending.popleft()
-        host = _counters_to_host(snap, self.nb)
+        with jax.profiler.TraceAnnotation("repro.sync"):
+            host = _counters_to_host(snap, self.nb)
         self.rounds_done = b
         self.final = host
         if b <= self.cfgs[0].warmup_rounds:
@@ -581,128 +584,126 @@ class _GroupRun:
         while self.pending and not self.stopped:
             self._resolve_one()
         self.pending.clear()
-        self.wall = time.time() - self.t0
 
     def finish(self, time_sink: dict | None = None) -> list[SimResult]:
         """Assemble per-cell :class:`SimResult`s (per-cell configs drive
-        cost/arrival accounting; identical to the serial assembly)."""
-        if self.final is None:
-            self.final = _read_counters(self.state, self.nb)
-        if time_sink is not None:
-            time_sink["wall_s"] = self.wall
-            time_sink["group_cells"] = self.n
+        cost/arrival accounting; identical to the serial assembly).
+        ``time_sink``, where given, receives ``group_cells``."""
+        with jax.profiler.TraceAnnotation("repro.finish"):
+            if self.final is None:
+                self.final = _read_counters(self.state, self.nb)
+            if time_sink is not None:
+                time_sink["group_cells"] = self.n
 
-        results = []
-        for i in range(self.n):
-            cfg = self.cfgs[i]
-            cm = cfg.cost
-            snap, wsnap, ri, wri = self.snaps[i] or (
-                _cell_slice(self.final, i),
-                _cell_slice(self.warm, i),
-                self.rounds_done,
-                self.warm_rounds,
-            )
-            commits = int(snap["commits"]) - int(wsnap["commits"])
-            meas_rounds = ri - wri
-            sim_seconds = meas_rounds * cm.round_seconds
-            cat = snap["cat"].astype(np.int64) - wsnap["cat"].astype(
-                np.int64
-            )
-            total_lane_rounds = max(int(cat.sum()), 1)
-            names = ["idle", "exec", "lock", "wait", "deadlock", "msg"]
-            breakdown = {
-                nm: float(cat[k]) / total_lane_rounds
-                for k, nm in enumerate(names)
-            }
+            results = []
+            for i in range(self.n):
+                cfg = self.cfgs[i]
+                cm = cfg.cost
+                snap, wsnap, ri, wri = self.snaps[i] or (
+                    _cell_slice(self.final, i),
+                    _cell_slice(self.warm, i),
+                    self.rounds_done,
+                    self.warm_rounds,
+                )
+                commits = int(snap["commits"]) - int(wsnap["commits"])
+                meas_rounds = ri - wri
+                sim_seconds = meas_rounds * cm.round_seconds
+                cat = snap["cat"].astype(np.int64) - wsnap["cat"].astype(
+                    np.int64
+                )
+                total_lane_rounds = max(int(cat.sum()), 1)
+                names = ["idle", "exec", "lock", "wait", "deadlock", "msg"]
+                breakdown = {
+                    nm: float(cat[k]) / total_lane_rounds
+                    for k, nm in enumerate(names)
+                }
 
-            def _delta(k):
-                return int(np.asarray(snap.get(k, 0))) - int(
-                    np.asarray(wsnap.get(k, 0))
-                )
+                def _delta(k):
+                    return int(np.asarray(snap.get(k, 0))) - int(
+                        np.asarray(wsnap.get(k, 0))
+                    )
 
-            # goodput split (committed <= admitted <= offered): admitted
-            # = arrival-stream consumption minus queue-side policy
-            # drops; offered = the arrival schedule's output over the
-            # measurement window. Open arrival only — closed-loop cells
-            # keep offered=0 so their metrics rows (and cached benchmark
-            # hashes) keep the pre-layer shape.
-            rejected = _delta("pol_rejected")
-            shed = _delta("pol_shed")
-            admitted = _delta("next_txn") - rejected - shed
-            if cfg.epoch_interval_rounds > 0:
-                offered = engine_lib.offered_by_round(
-                    cfg, self.plans[i], ri
-                ) - engine_lib.offered_by_round(cfg, self.plans[i], wri)
-            else:
-                offered = 0
-            met = None
-            if "lat_hist" in snap:
-                # histogram counters are cumulative (warmup-subtracted);
-                # queue samples are point-in-time (grid points past the
-                # capture round stay zero)
-                hist = snap["lat_hist"].astype(np.int64) - np.asarray(
-                    wsnap.get("lat_hist", 0)
-                ).astype(np.int64)
-                qiv = engine_lib.qgrid_interval(cfg)
-                qgrid = (
-                    np.arange(metrics_lib.QDEPTH_SAMPLES, dtype=np.int64)
-                    + 1
-                ) * qiv
-                met = metrics_lib.build_metrics(
-                    lat_hist=hist,
-                    q_depth=snap["q_depth"],
-                    q_inflight=snap["q_inflight"],
-                    q_grid=qgrid,
-                    breakdown=breakdown,
-                    exec_lane_rounds=total_lane_rounds,
-                    plan_busy_rounds=int(snap.get("plan_busy_int", 0))
-                    - int(np.asarray(wsnap.get("plan_busy_int", 0))),
-                    plan_lane_rounds=cfg.n_planner_lanes * meas_rounds,
-                    committed=commits,
-                    admitted=admitted,
-                    offered=offered,
-                    rejected=rejected,
-                    shed=shed,
-                    timedout=_delta("pol_timedout"),
-                    sacrificed=_delta("pol_sacrificed"),
+                # goodput split (committed <= admitted <= offered): admitted
+                # = arrival-stream consumption minus queue-side policy
+                # drops; offered = the arrival schedule's output over the
+                # measurement window. Open arrival only — closed-loop cells
+                # keep offered=0 so their metrics rows (and cached benchmark
+                # hashes) keep the pre-layer shape.
+                rejected = _delta("pol_rejected")
+                shed = _delta("pol_shed")
+                admitted = _delta("next_txn") - rejected - shed
+                if cfg.epoch_interval_rounds > 0:
+                    offered = engine_lib.offered_by_round(
+                        cfg, self.plans[i], ri
+                    ) - engine_lib.offered_by_round(cfg, self.plans[i], wri)
+                else:
+                    offered = 0
+                met = None
+                if "lat_hist" in snap:
+                    # histogram counters are cumulative (warmup-subtracted);
+                    # queue samples are point-in-time (grid points past the
+                    # capture round stay zero)
+                    hist = snap["lat_hist"].astype(np.int64) - np.asarray(
+                        wsnap.get("lat_hist", 0)
+                    ).astype(np.int64)
+                    qiv = engine_lib.qgrid_interval(cfg)
+                    qgrid = (
+                        np.arange(metrics_lib.QDEPTH_SAMPLES, dtype=np.int64)
+                        + 1
+                    ) * qiv
+                    met = metrics_lib.build_metrics(
+                        lat_hist=hist,
+                        q_depth=snap["q_depth"],
+                        q_inflight=snap["q_inflight"],
+                        q_grid=qgrid,
+                        breakdown=breakdown,
+                        exec_lane_rounds=total_lane_rounds,
+                        plan_busy_rounds=int(snap.get("plan_busy_int", 0))
+                        - int(np.asarray(wsnap.get("plan_busy_int", 0))),
+                        plan_lane_rounds=cfg.n_planner_lanes * meas_rounds,
+                        committed=commits,
+                        admitted=admitted,
+                        offered=offered,
+                        rejected=rejected,
+                        shed=shed,
+                        timedout=_delta("pol_timedout"),
+                        sacrificed=_delta("pol_sacrificed"),
+                    )
+                results.append(
+                    SimResult(
+                        commits=commits,
+                        aborts_deadlock=int(snap["aborts_dl"])
+                        - int(wsnap["aborts_dl"]),
+                        aborts_ollp=int(snap["aborts_ollp"])
+                        - int(wsnap["aborts_ollp"]),
+                        wasted_ops=int(snap["wasted"]) - int(wsnap["wasted"]),
+                        rounds=meas_rounds,
+                        sim_seconds=sim_seconds,
+                        throughput_txn_s=commits / max(sim_seconds, 1e-12),
+                        breakdown=breakdown,
+                        raw=dict(
+                            total_commits=int(snap["commits"]),
+                            next_txn=int(snap["next_txn"]),
+                            rounds_total=ri,
+                            steps_executed=int(snap["steps"]),
+                            group_cells=self.n,
+                            engine_version=ENGINE_VERSION,
+                            **{
+                                k: int(snap[k])
+                                - int(np.asarray(wsnap.get(k, 0)))
+                                for k in _OPT_SCALARS
+                                if k in snap
+                            },
+                        ),
+                        metrics=met,
+                    )
                 )
-            results.append(
-                SimResult(
-                    commits=commits,
-                    aborts_deadlock=int(snap["aborts_dl"])
-                    - int(wsnap["aborts_dl"]),
-                    aborts_ollp=int(snap["aborts_ollp"])
-                    - int(wsnap["aborts_ollp"]),
-                    wasted_ops=int(snap["wasted"]) - int(wsnap["wasted"]),
-                    rounds=meas_rounds,
-                    sim_seconds=sim_seconds,
-                    throughput_txn_s=commits / max(sim_seconds, 1e-12),
-                    breakdown=breakdown,
-                    raw=dict(
-                        total_commits=int(snap["commits"]),
-                        next_txn=int(snap["next_txn"]),
-                        rounds_total=ri,
-                        steps_executed=int(snap["steps"]),
-                        wall_s_group=round(self.wall, 3),
-                        group_cells=self.n,
-                        engine_version=ENGINE_VERSION,
-                        **{
-                            k: int(snap[k])
-                            - int(np.asarray(wsnap.get(k, 0)))
-                            for k in _OPT_SCALARS
-                            if k in snap
-                        },
-                    ),
-                    metrics=met,
-                )
-            )
-        return results
+            return results
 
 
 def simulate_plans(
     cfg: EngineConfig,
     plans: list,
-    time_sink: dict | None = None,
     mode: SweepMode | None = None,
 ) -> list[SimResult]:
     """Run one simulation per plan, sharing a single compiled runner.
@@ -718,7 +719,7 @@ def simulate_plans(
         mode = sweep_mode()
     run = _GroupRun([cfg] * len(plans), plans, mode)
     run.drive()
-    return run.finish(time_sink)
+    return run.finish()
 
 
 def _plan_shape_sig(p: dict) -> tuple:
@@ -743,46 +744,50 @@ def run_cells(
     """
     if mode is None:
         mode = sweep_mode()
-    plans = [engine_lib.make_plan(cfg, wl) for cfg, wl in cells]
-    ps = [
-        engine_lib.plan_device(cfg, pl)
-        for (cfg, _wl), pl in zip(cells, plans)
-    ]
-    groups: dict = {}
-    for idx, ((cfg, _wl), plan, p) in enumerate(zip(cells, plans, ps)):
-        key = (
-            cfg.trace_statics(),
-            (cfg.max_rounds, cfg.warmup_rounds, cfg.chunk_rounds,
-             cfg.target_commits),
-            engine_lib.plan_meta(cfg, plan),
-            _plan_shape_sig(p),
-        )
-        groups.setdefault(key, []).append(idx)
-
-    order = list(groups.values())
-    runs: list[_GroupRun | None] = [None] * len(order)
-
-    def ensure(gi: int) -> _GroupRun:
-        if runs[gi] is None:
-            idxs = order[gi]
-            runs[gi] = _GroupRun(
-                [cells[i][0] for i in idxs],
-                [plans[i] for i in idxs],
-                mode,
-                ps=[ps[i] for i in idxs],
+    with jax.profiler.TraceAnnotation(
+        "repro.run_cells", cells=len(cells)
+    ) as span:
+        plans = [engine_lib.make_plan(cfg, wl) for cfg, wl in cells]
+        ps = []
+        for (cfg, _wl), pl in zip(cells, plans):
+            with jax.profiler.TraceAnnotation("repro.upload"):
+                ps.append(engine_lib.plan_device(cfg, pl))
+        groups: dict = {}
+        for idx, ((cfg, _wl), plan, p) in enumerate(zip(cells, plans, ps)):
+            key = (
+                cfg.trace_statics(),
+                (cfg.max_rounds, cfg.warmup_rounds, cfg.chunk_rounds,
+                 cfg.target_commits),
+                engine_lib.plan_meta(cfg, plan),
+                _plan_shape_sig(p),
             )
-        return runs[gi]
+            groups.setdefault(key, []).append(idx)
+        span.set_metadata(groups=len(groups))
 
-    out: list = [None] * len(cells)
-    for gi, idxs in enumerate(order):
-        g = ensure(gi)
-        prefetch = None
-        if mode.pipeline > 0 and gi + 1 < len(order):
-            # overlap the next group's compile + first dispatch with
-            # this group's execution
-            prefetch = lambda j=gi + 1: ensure(j).start()  # noqa: E731
-        g.drive(prefetch)
-        for idx, res in zip(idxs, g.finish()):
-            out[idx] = res
-        runs[gi] = None  # release state/plan buffers promptly
-    return out
+        order = list(groups.values())
+        runs: list[_GroupRun | None] = [None] * len(order)
+
+        def ensure(gi: int) -> _GroupRun:
+            if runs[gi] is None:
+                idxs = order[gi]
+                runs[gi] = _GroupRun(
+                    [cells[i][0] for i in idxs],
+                    [plans[i] for i in idxs],
+                    mode,
+                    ps=[ps[i] for i in idxs],
+                )
+            return runs[gi]
+
+        out: list = [None] * len(cells)
+        for gi, idxs in enumerate(order):
+            g = ensure(gi)
+            prefetch = None
+            if mode.pipeline > 0 and gi + 1 < len(order):
+                # overlap the next group's compile + first dispatch with
+                # this group's execution
+                prefetch = lambda j=gi + 1: ensure(j).start()  # noqa: E731
+            g.drive(prefetch)
+            for idx, res in zip(idxs, g.finish()):
+                out[idx] = res
+            runs[gi] = None  # release state/plan buffers promptly
+        return out
