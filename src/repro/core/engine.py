@@ -881,16 +881,6 @@ def _state0(cfg: EngineConfig, num_records: int, T: int, K: int):
         reach=jnp.zeros((T, T), jnp.bool_),
         wh=jnp.full((R,), -1, i32),
         rc=jnp.zeros((R,), i32),
-        # packed per-record cost-model state (one gather + one scatter per
-        # round each instead of five):
-        #   heat[:, 0] = ep, heat[:, 1] = cnt_cur, heat[:, 2] = cnt_prev
-        #   line[:, 0] = lnf (line-free round), line[:, 1] = last_lane
-        heat=jnp.concatenate(
-            [jnp.full((R, 1), -10, i32), jnp.zeros((R, 2), i32)], axis=1
-        ),
-        line=jnp.concatenate(
-            [jnp.zeros((R, 1), i32), jnp.full((R, 1), -1, i32)], axis=1
-        ),
         commits=jnp.zeros((), i32),
         aborts_dl=jnp.zeros((), i32),
         aborts_ollp=jnp.zeros((), i32),
@@ -903,24 +893,39 @@ def _state0(cfg: EngineConfig, num_records: int, T: int, K: int):
         q_depth=jnp.zeros((QDEPTH_SAMPLES,), i32),
         q_inflight=jnp.zeros((QDEPTH_SAMPLES,), i32),
     )
+    # Per-record side state is held as 1-D [R] columns, one per field,
+    # like wh and rc: a row scatter or gather into an [R, F] array with a
+    # small minor dimension costs several times a 1-D one on the TPU
+    # (the minor dimension pads to 128 lanes, which puts the array in
+    # HBM, and each row is a serial update there), and a 2-D array read
+    # by one stage in another layout than the one it is scattered in
+    # gets a whole-array relayout copy every round.
     if cfg.protocol != "orthrus":
-        # carried per-record same-round contention sums (see stage 9 of
-        # make_step): a single scatter-add per round removes the previous
-        # round's contributions (agg_prev_*) and applies the current
-        # ones, so the [R, 3] buffer is mutated once and only *then*
-        # read — XLA aliases it in place. (Any formulation that gathers
-        # the buffer both before and after its scatter makes copy
-        # insertion duplicate the whole [R, 3] buffer every round.)
-        s["agg_sum"] = jnp.zeros((R, 3), i32)
+        # stage 9's cost model: sharer heat (epoch, count in the current
+        # epoch, count in the previous one) and the meta-data line
+        # (line-free round, last lane to touch it)
+        s["heat"] = (jnp.full((R,), -10, i32), jnp.zeros((R,), i32),
+                     jnp.zeros((R,), i32))
+        s["line"] = (jnp.zeros((R,), i32), jnp.full((R,), -1, i32))
+        # carried per-record same-round contention sums (active entries,
+        # new ops, queue mutations; see stage 9 of make_step): a single
+        # scatter-add per column and round removes the previous round's
+        # contributions (agg_prev_*) and applies the current ones, so
+        # each column is mutated once and only *then* read — XLA
+        # aliases it in place. (Any formulation that gathers a column
+        # both before and after its scatter makes copy insertion
+        # duplicate the whole [R] column every round.)
+        s["agg_sum"] = tuple(jnp.zeros((R,), i32) for _ in range(3))
         s["agg_prev_idx"] = jnp.full((T, K), R, i32)
-        s["agg_prev_upd"] = jnp.zeros((T, K, 3), i32)
+        s["agg_prev_upd"] = tuple(jnp.zeros((T, K), i32) for _ in range(3))
     if cfg.release_path == "csr" and cfg.deadlock_scheme != "none":
-        # csr wait-for: carried per-record packed reader bitmask
-        # (bit u of rdr[q, u // 32] = slot u holds >= 1 granted read
-        # entry on record q), maintained incrementally at grant /
-        # release — the deadlock stage gathers waiters' digests from it
-        # instead of building the dense [T, T, K] key-equality tensor.
-        s["rdr"] = jnp.zeros((R, (T + 31) // 32), i32)
+        # csr wait-for: carried per-record reader bitmask, one [R] column
+        # per 32 slots (bit u % 32 of rdr[u // 32][q] = slot u holds >= 1
+        # granted read entry on record q), maintained incrementally at
+        # grant / release — the deadlock stage gathers waiters' digests
+        # from it instead of building the dense [T, T, K] key-equality
+        # tensor.
+        s["rdr"] = tuple(jnp.zeros((R,), i32) for _ in range((T + 31) // 32))
     # overload-robustness counters (carried scalars; sweep._OPT_SCALARS
     # picks up whichever are present). Keyed on the same statics as the
     # step builder, so vmapped cells always share a state shape.
@@ -959,12 +964,12 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
     pending lock request per slot (the ``kptr`` column), so FIFO grant
     decisions reduce to an all-pairs [T, T] enqueue-stamp comparison
     over compact [T] request vectors, and per-key same-round contention
-    counts come from the carried ``agg_sum`` accumulator (one
-    cancel-previous-and-apply scatter-add per round). This replaces the
-    legacy engine's (key, enq) sort + segmented scans — the hottest ops
-    of its round loop on saturated lock tables. ORTHRUS admits whole
-    key-groups at once (several pending entries per slot), so it keeps
-    the sorted segmented-grant path.
+    counts come from the carried ``agg_sum`` columns (one
+    cancel-previous-and-apply scatter-add per column and round). This
+    replaces the legacy engine's (key, enq) sort + segmented scans — the
+    hottest ops of its round loop on saturated lock tables. ORTHRUS
+    admits whole key-groups at once (several pending entries per slot),
+    so it keeps the sorted segmented-grant path.
     """
     cm = cfg.cost
     T, K = cfg.n_slots, meta.max_keys
@@ -1018,6 +1023,9 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
     need_rdr = use_csr and dl != "none"
     rdr_word = slot_ids // 32  # reader-bitmask word / bit per slot
     rdr_bit = jnp.int32(1) << (slot_ids % 32)
+    # the slots of word w: a static slice, so each word's scatter takes
+    # only its own slots' entries
+    rdr_slots = [slice(32 * w, 32 * (w + 1)) for w in range((T + 31) // 32)]
     use_pallas = cfg.is_orthrus and _use_pallas(cfg)
     if use_pallas:
         from repro.kernels.lock_grant.ops import lock_grant as _lock_grant
@@ -1293,11 +1301,11 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
                         & (kk[None, None, :] < kk[None, :, None])
                     ).any(-1)
                     first_rd = is_rd & ~dup
-                    s["rdr"] = s["rdr"].at[
-                        jnp.where(first_rd, rel_k, R),
-                        jnp.broadcast_to(rdr_word[:, None], (T, K)),
-                    ].add(
-                        jnp.where(first_rd, -rdr_bit[:, None], 0), mode="drop"
+                    rel_idx = jnp.where(first_rd, rel_k, R)
+                    rel_bit = jnp.where(first_rd, -rdr_bit[:, None], 0)
+                    s["rdr"] = tuple(
+                        col.at[rel_idx[ws]].add(rel_bit[ws], mode="drop")
+                        for col, ws in zip(s["rdr"], rdr_slots)
                     )
                 s["granted"] = s["granted"] & ~rel_entries
 
@@ -1487,9 +1495,12 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
                         & (modes == MODE_READ)
                     ).any(axis=1)
                     new_rd = g_rd_t & ~already
-                    s["rdr"] = s["rdr"].at[
-                        jnp.where(new_rd, rkey, R), rdr_word
-                    ].add(jnp.where(new_rd, rdr_bit, 0), mode="drop")
+                    new_idx = jnp.where(new_rd, rkey, R)
+                    new_bit = jnp.where(new_rd, rdr_bit, 0)
+                    s["rdr"] = tuple(
+                        col.at[new_idx[ws]].add(new_bit[ws], mode="drop")
+                        for col, ws in zip(s["rdr"], rdr_slots)
+                    )
             s["granted"] = s["granted"] | grant
 
         # ------------------------------------------------ 8. deadlock logic
@@ -1514,14 +1525,16 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
                 if use_csr:
                     # compact wait-for: the writer holding my key is one
                     # lock-table gather; read holders come from the carried
-                    # per-record reader bitmask (bit u of word u // 32) —
-                    # [T] + [T, W] gathers and a [T, T] bit extraction
+                    # per-record reader bitmask (bit u % 32 of word u // 32)
+                    # — [T] + W [T] gathers and a [T, T] bit extraction
                     # replace the dense [T, T, K] key-equality tensor. A
                     # read holder conflicts only with a write waiter; a
                     # write holder conflicts with everyone.
                     wt_c = jnp.minimum(waitkey, R - 1)
                     hw = s["wh"][wt_c]  # [T] writer of my key (-1 = none)
-                    dig = s["rdr"][wt_c]  # [T, W] packed reader bits
+                    dig = jnp.stack(
+                        [col[wt_c] for col in s["rdr"]], axis=1
+                    )  # [T, W] packed reader bits
                     rd_bits = jnp.take(dig, rdr_word, axis=1)  # [T, T]
                     adj_rd = ((rd_bits >> (slot_ids % 32)[None, :]) & 1) != 0
                     adj = (
@@ -1609,42 +1622,44 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
             if not cfg.is_orthrus:
                 newop = newop2d  # fresh lock-table ops this round: reqs+releases
                 mutate = newop & ~abort_dl[:, None]  # dies don't enqueue
-                # per-key same-round contention via the carried agg_sum buffer
-                # (columns: active entries, new ops, queue mutations). One
-                # scatter-add per round cancels the previous round's
-                # contributions and applies this round's, so the buffer holds
-                # exactly "this round" when gathered and is never read before
-                # a pending mutation (no [R]-sized copy, see _state0).
+                # per-key same-round contention via the carried agg_sum
+                # columns (active entries, new ops, queue mutations). One
+                # scatter-add per column and round cancels the previous
+                # round's contributions and applies this round's, so each
+                # column holds exactly "this round" when gathered and is
+                # never read before a pending mutation (no [R]-sized copy,
+                # see _state0).
                 active2d = pend2d | rel_entries
                 aidx = jnp.where(active2d, keys, R)
-                sum_upd = jnp.stack(
-                    [active2d.astype(i32), newop.astype(i32),
-                     mutate.astype(i32)], axis=-1,
-                )  # [T, K, 3]
+                sum_upd = (
+                    active2d.astype(i32), newop.astype(i32), mutate.astype(i32)
+                )
                 idx_cat = jnp.concatenate([s["agg_prev_idx"], aidx], axis=0)
-                upd_cat = jnp.concatenate([-s["agg_prev_upd"], sum_upd], axis=0)
-                agg_s = s["agg_sum"].at[idx_cat].add(upd_cat, mode="drop")
-                s["agg_sum"] = agg_s
+                s["agg_sum"] = tuple(
+                    col.at[idx_cat].add(
+                        jnp.concatenate([-prev, upd], axis=0), mode="drop"
+                    )
+                    for col, prev, upd in zip(
+                        s["agg_sum"], s["agg_prev_upd"], sum_upd
+                    )
+                )
                 s["agg_prev_idx"] = aidx
                 s["agg_prev_upd"] = sum_upd
                 e = r >> EPOCH_BITS
                 opk_r = jnp.minimum(jnp.where(newop, keys, 0), R - 1)
-                seg = agg_s[opk_r]  # [T, K, 3], this round's per-key totals
-                contend = seg[..., 0]
-                new_in_seg = seg[..., 1]
-                mut_in_seg = seg[..., 2]
-                heat_k = s["heat"][opk_r]  # [T, K, 3] = (ep, cnt_cur, cnt_prev)
-                ep_k = heat_k[..., 0]
-                cur_k = heat_k[..., 1]
-                prev_k = heat_k[..., 2]
-                line_k = s["line"][opk_r]  # [T, K, 2] = (lnf, last_lane)
+                # this round's per-key totals
+                contend, new_in_seg, mut_in_seg = (
+                    col[opk_r] for col in s["agg_sum"]
+                )
+                ep_k, cur_k, prev_k = (col[opk_r] for col in s["heat"])
+                lnf_cur, last_lane = (col[opk_r] for col in s["line"])
                 sharers = jnp.where(
                     ep_k == e,
                     jnp.maximum(prev_k, cur_k),
                     jnp.where(ep_k == e - 1, cur_k, 0),
                 )
                 lane2d = jnp.broadcast_to(lane_of[:, None], (T, K))
-                remote = line_k[..., 1] != lane2d
+                remote = last_lane != lane2d
                 coh = jnp.where(
                     remote,
                     cm.coherence_cycles_per_sharer
@@ -1659,31 +1674,34 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
                         contend - 1, 0
                     )
                 dur = rounds_of(lock_op_cycles + coh)
-                lnf_cur = line_k[..., 0]
                 backlog = jnp.maximum(jnp.where(mutate, lnf_cur - r, 0), 0)
                 charge = jnp.where(newop, backlog + dur, 0).sum(axis=1)
                 # occupancy: same-round queue mutations serialize on the line
                 occupy = jnp.where(mutate, mut_in_seg * dur, 0)
                 tgt = jnp.maximum(lnf_cur, r) + occupy
                 opk_heat = jnp.where(newop, opk_r, R)
-                # packed writes: lnf applies only at mutating entries (a die
-                # probe occupies nothing), masked inside the max via INT32_MIN;
-                # last_lane applies at every fresh op. Heat values are
+                # lnf applies only at mutating entries (a die probe occupies
+                # nothing), masked inside the max via INT32_MIN; last_lane
+                # applies at every fresh op. Heat values are
                 # per-key-identical, so duplicate-index set is idempotent.
-                line_upd = jnp.stack(
-                    [jnp.where(mutate, tgt, jnp.iinfo(jnp.int32).min), lane2d],
-                    axis=-1,
+                line_upd = (
+                    jnp.where(mutate, tgt, jnp.iinfo(jnp.int32).min), lane2d
                 )
-                s["line"] = s["line"].at[opk_heat].max(line_upd, mode="drop")
+                s["line"] = tuple(
+                    col.at[opk_heat].max(upd, mode="drop")
+                    for col, upd in zip(s["line"], line_upd)
+                )
                 new_prev = jnp.where(
                     ep_k == e, prev_k, jnp.where(ep_k == e - 1, cur_k, 0)
                 )
                 new_cur = jnp.where(ep_k == e, cur_k, 0) + new_in_seg
-                heat_upd = jnp.stack(
-                    [jnp.broadcast_to(e, new_cur.shape), new_cur, new_prev],
-                    axis=-1,
+                heat_upd = (
+                    jnp.broadcast_to(e, new_cur.shape), new_cur, new_prev
                 )
-                s["heat"] = s["heat"].at[opk_heat].set(heat_upd, mode="drop")
+                s["heat"] = tuple(
+                    col.at[opk_heat].set(upd, mode="drop")
+                    for col, upd in zip(s["heat"], heat_upd)
+                )
                 charged = charge > 0
                 busy_until = jnp.where(
                     charged, jnp.maximum(busy_until, r) + charge,

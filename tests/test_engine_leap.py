@@ -13,12 +13,16 @@ repro.core.sweep); tests/test_golden_traces.py pins the same contract
 against committed fixtures across PRs.
 """
 
+import dataclasses
+
+import numpy as np
 import pytest
 
 from hypothesis_compat import given, settings, st
 from repro.core import sweep
 from repro.core.engine import EngineConfig, run_simulation
-from repro.core.workloads import WorkloadConfig, make_workload
+from repro.core.workloads import (MODE_READ, MODE_WRITE, WorkloadConfig,
+                                  make_workload)
 
 FAST = dict(max_rounds=2000, warmup_rounds=500, chunk_rounds=500,
             target_commits=10**9)
@@ -119,17 +123,47 @@ def test_leap_matches_dense_property(protocol, num_hot, read_only, seed):
     assert _fingerprint(leap) == _fingerprint(dense)
 
 
-@pytest.mark.parametrize("protocol", sorted(PROTO_KW))
-def test_packed_matches_legacy(ycsb_hot, protocol):
+@pytest.fixture(scope="module")
+def ycsb_hot_rw():
+    """YCSB on 48 hot records in which each txn reads the keys of its own
+    parity and writes the rest: hot records are read-shared by many slots
+    at once, so write waiters' wait-for edges run through the reader
+    bitmask (and no txn reads a record it then writes)."""
+    wl = make_workload(
+        WorkloadConfig(kind="ycsb", num_txns=512, num_records=20_000,
+                       num_hot=48, seed=0)
+    )
+    parity = (wl.keys + np.arange(len(wl.keys))[:, None]) % 2
+    modes = np.where(parity == 0, MODE_READ, MODE_WRITE)
+    return dataclasses.replace(wl, modes=modes.astype(wl.modes.dtype))
+
+
+# Past one 32-slot reader-bitmask word: 40 lanes take two words, 80
+# take three.
+LEGACY_CASES = [pytest.param(p, None, "ycsb_hot", id=p)
+                for p in sorted(PROTO_KW)] + [
+    pytest.param(p, n, "ycsb_hot_rw", id=f"{p}-{n}-rw")
+    for p in ("twopl_dreadlocks", "twopl_waitdie", "twopl_waitfor")
+    for n in (40, 80)
+]
+
+
+@pytest.mark.parametrize("protocol,n_exec,wl_fixture", LEGACY_CASES)
+def test_packed_matches_legacy(request, protocol, n_exec, wl_fixture):
     """The packed [SLOT_F, T] state-matrix engine must reproduce the
     frozen pre-rewrite engine bit-exactly, per protocol."""
-    packed = _run(protocol, ycsb_hot, leap=True)
-    legacy_cfg = EngineConfig(protocol=protocol, event_leap=True,
-                              state_layout="legacy",
-                              **PROTO_KW[protocol], **FAST)
-    legacy = run_simulation(legacy_cfg, ycsb_hot)
+    wl = request.getfixturevalue(wl_fixture)
+    kw = dict(PROTO_KW[protocol])
+    if n_exec is not None:
+        kw["n_exec"] = n_exec
+    packed, legacy = (
+        run_simulation(EngineConfig(protocol=protocol, event_leap=True,
+                                    state_layout=layout, **kw, **FAST), wl)
+        for layout in ("packed", "legacy")
+    )
     assert _fingerprint(packed) == _fingerprint(legacy)
     assert packed.raw["steps_executed"] == legacy.raw["steps_executed"]
+    assert packed.commits > 0
 
 
 @settings(max_examples=8, deadline=None)

@@ -3,9 +3,11 @@
 The interpret-mode kernel tests cannot see what the chip's compiler
 refuses (``cumsum``, single-element extraction, slices off the
 ``(8, 128)`` tiling). These tests lower the ``lock_grant`` and
-``dep_wavefront`` kernels, and the ``twopl_waitdie`` and ``dgcc``
-chunk runners, for one chip of a described ``v5e:2x2`` topology and
-compile them with the installed TPU compiler. Nothing runs.
+``dep_wavefront`` kernels, and the ``twopl_waitdie``, ``twopl_dreadlocks``
+and ``dgcc`` chunk runners, for one chip of a described ``v5e:2x2``
+topology and compile them with the installed TPU compiler, and check
+the compiled lock-table loop for per-record copies and row scatters.
+Nothing runs.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and under pytest-xdist
@@ -15,6 +17,7 @@ be read back without one.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,9 +69,10 @@ def no_persistent_cache():
         compilation_cache.reset_cache()
 
 
-def _spec(x, sharding):
+def _spec(x, sharding, lead=()):
     x = np.asarray(x) if not hasattr(x, "dtype") else x
-    return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding)
+    return jax.ShapeDtypeStruct(lead + np.shape(x), x.dtype,
+                                sharding=sharding)
 
 
 def _kernel_count(compiled) -> int:
@@ -107,6 +111,29 @@ _SIM = dict(max_rounds=6000, warmup_rounds=2000, chunk_rounds=2000,
             target_commits=100_000_000)
 
 
+def _compile_runner(eng, one_chip, cells=0):
+    """Compile the chunk runner of ``eng`` on ``_YCSB`` for one described
+    chip: one cell, or a vmapped group of ``cells``. Returns the compiled
+    runner and the plan's shapes."""
+    cfg = EngineConfig(**eng, **_SIM)
+    plan = engine_lib.make_plan(cfg, make_workload(WorkloadConfig(**_YCSB)))
+    meta = engine_lib.plan_meta(cfg, plan)
+    p = engine_lib.plan_device(cfg, plan)
+    if cfg.is_batch_planned:
+        state = engine_lib._batch_state0(cfg, plan, cfg.n_slots)
+    else:
+        state = engine_lib._state0(cfg, plan.num_records, cfg.n_slots,
+                                   meta.max_keys)
+    lead = (cells,) if cells else ()
+    runner = sweep.get_runner(cfg, meta, batched=bool(cells))
+    spec = functools.partial(_spec, sharding=one_chip, lead=lead)
+    compiled = runner.lower(
+        jax.tree.map(spec, p), jax.tree.map(spec, state),
+        jax.ShapeDtypeStruct(lead, jnp.int32, sharding=one_chip),
+    ).compile()
+    return compiled, meta
+
+
 @pytest.mark.parametrize("eng,kernels", [
     (dict(protocol="twopl_waitdie", n_exec=40), 0),
     (dict(protocol="dgcc", n_cc=8, n_exec=32, window=4,
@@ -117,19 +144,82 @@ def test_chunk_runner_compiles(eng, kernels, one_chip, no_persistent_cache,
     # this process is on the CPU, where the kernel op would resolve to
     # the interpreter; the described chip needs the compiled kernel
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    cfg = EngineConfig(**eng, **_SIM)
-    plan = engine_lib.make_plan(cfg, make_workload(WorkloadConfig(**_YCSB)))
-    meta = engine_lib.plan_meta(cfg, plan)
-    p = engine_lib.plan_device(cfg, plan)
-    if cfg.is_batch_planned:
-        state = engine_lib._batch_state0(cfg, plan, cfg.n_slots)
-    else:
-        state = engine_lib._state0(cfg, plan.num_records, cfg.n_slots,
-                                   meta.max_keys)
-    runner = sweep.get_runner(cfg, meta, batched=False)
-    spec = functools.partial(_spec, sharding=one_chip)
-    compiled = runner.lower(
-        jax.tree.map(spec, p), jax.tree.map(spec, state),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-    ).compile()
+    compiled, _ = _compile_runner(eng, one_chip)
     assert _kernel_count(compiled) == kernels
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_OP = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}"
+)
+
+
+def _ops(text: str, loop_only: bool) -> list[tuple[str, tuple[int, ...]]]:
+    """``(opcode, dims)`` of the array-shaped instructions of a compiled
+    module's text: all of them, or only those a ``while`` body runs
+    (the body and every computation it calls, fusions included)."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    names = set(comps)
+    if loop_only:
+        names, todo = set(), re.findall(r"body=%([\w.\-]+)", text)
+        while todo:
+            c = todo.pop()
+            if c in names or c not in comps:
+                continue
+            names.add(c)
+            for line in comps[c]:
+                for one, many in _CALLED.findall(line):
+                    todo += [one] if one else re.findall(r"%([\w.\-]+)",
+                                                         many)
+    ops = []
+    for c in names:
+        for line in comps[c]:
+            m = _OP.match(line)
+            if m:
+                dims = tuple(int(d) for d in m.group(1).split(",") if d)
+                ops.append((m.group(2), dims))
+    return ops
+
+
+@pytest.mark.parametrize("eng,cells", [
+    pytest.param(dict(protocol="twopl_waitdie", n_exec=40), 0,
+                 id="waitdie"),
+    pytest.param(dict(protocol="twopl_waitdie", n_exec=40), 2,
+                 id="waitdie-vmapped"),
+    # 80 slots: three reader-bitmask words
+    pytest.param(dict(protocol="twopl_dreadlocks", n_exec=80), 0,
+                 id="dreadlocks-80"),
+])
+def test_record_columns_not_copied(eng, cells, one_chip, no_persistent_cache):
+    """The lock-table step's per-record state is 1-D [R] columns (a
+    vmapped group adds a leading cell axis): the round loop copies or
+    scatters into no [R, F] array, and a single cell's runner copies no
+    per-record array. A row scatter into an [R, F] array costs several
+    times a 1-D one on the chip, and an [R, F] array read in another
+    layout than it is written in is copied whole every round."""
+    compiled, meta = _compile_runner(eng, one_chip, cells)
+    text = compiled.as_text()
+    R = meta.num_records
+    loop = _ops(text, loop_only=True)
+    assert any(op == "scatter" and R in dims for op, dims in loop)
+    for op, dims in loop:
+        if op in ("copy", "scatter") and R in dims:
+            assert dims.index(R) == len(dims) - 1, (op, dims)
+    if cells:
+        # the vmapped loop's per-lane select copies [cells, R] columns
+        # each round; on a TPU v5e that measured faster than a loop
+        # written out to skip the select for them
+        return
+    copied = [dims for op, dims in _ops(text, loop_only=False)
+              if op == "copy" and R in dims]
+    assert not copied, copied
