@@ -33,7 +33,7 @@ def readings(cell, seeds) -> list[dict]:
     for seed in seeds:
         call = run.build_pool(cell, seed)[0]
         bad, cells = 0, []
-        for _, i in run.sample([call], seed, run.CHECK_CELLS):
+        for _, i in run.sample([call], seed, run.CHECK_CELLS * cell.chips):
             c = call.cells[i]
             w = call.streams[c.stream]
             t0 = time.perf_counter()

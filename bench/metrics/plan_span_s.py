@@ -1,6 +1,6 @@
 """plan_span_s (s): mean duration of the program's ``repro.plan`` host
 span (``engine.make_plan``, one per simulation cell) in the completed
-calls: ``plan_s`` measured where the work happens."""
+calls."""
 
 from bench import scopes
 

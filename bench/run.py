@@ -1,6 +1,6 @@
 """Chip benchmark of the OLTP simulator: one cell of BENCHMARK.json, once.
 
-    python3 bench/run.py --workload ycsb10m_40c.waitdie_hot64 \
+    python3 bench/run.py --workload <config>.<traffic> \
         --seed 7 --seconds 10 --trace 0
 
 Run from the root of a checkout; the script puts ``src/`` and the root
@@ -8,17 +8,20 @@ on the path itself and does everything in this one process, which holds
 the chip. No TPU, or fewer chips than the cell asks for, is an error.
 
 A cell names a configuration (``bench/configs/<config>.json``: the
-deployment, its budgets and guarantees) and a traffic mix
+deployment, its budgets and guarantees; its ``workload.kind`` names the
+stream's generator, ``bench/gen/<kind>.py``) and a traffic mix
 (``bench/traffic/<traffic>.json``: the mix's parameters, the streams and
-protocols of one call and the pool size). Per-layer
-metrics are read by ``bench/metrics/<name>.py``. Nothing here names a
-cell, a mix or a metric: a new one is a new file and an entry in
-BENCHMARK.json.
+protocols of one call and the pool size). Per-layer metrics are read by
+``bench/metrics/<name>.py``, and the reference plans a protocol with
+``bench/reference/plans/<protocol>.py``. Nothing here names a cell, a
+kind of stream, a mix, a protocol or a metric: a new deployment is
+files (a kind module where its stream is of a new kind, a configuration,
+a traffic file) and entries in BENCHMARK.json.
 
 One run:
 
 1. set-up (``setup_s``): generate a pool of calls from ``--seed`` with
-   the benchmark's own generator (``bench/gen.py``); plan each with the
+   the benchmark's own generators (``bench/gen/``); plan each with the
    program's ``engine.make_plan`` to learn its plan shapes
    (``engine.plan_meta``), and warm every distinct call shape through
    ``repro.core.sweep.run_cells`` on a two-round budget, so the
@@ -30,9 +33,10 @@ One run:
    completed (a cell is complete when its counters are on the host).
    Compiles inside the window are counted; a run with any is not correct;
 3. correctness, once the window has closed: a sample of the completed
-   cells, drawn from the seed, is simulated again by the reference
-   (``bench/reference``), which imports nothing of the program, and
-   every counter the cell reads back is compared exactly;
+   cells (``CHECK_CELLS`` per chip of the cell), drawn from the seed, is
+   simulated again by the reference (``bench/reference``), which imports
+   nothing of the program, and every counter the cell reads back is
+   compared exactly;
 4. with ``--trace 1`` the window runs under the profiler (in memory),
    shortened to ``TRACE_SECONDS`` (whole calls still) and cut at
    ``TRACE_CAP_SECONDS``, and the cell's per-layer metrics are read from
@@ -61,7 +65,9 @@ WARM_BUDGET = dict(max_rounds=2, warmup_rounds=0, chunk_rounds=1)
 # the sweep mode users get by default on the cell's chips, stated here so
 # that no REPRO_SWEEP_* variable in the environment changes it
 SWEEP_MODE = dict(pipeline=1, early_exit=True)
-# simulation cells checked against the reference, from one window call
+# simulation cells checked against the reference, from one window call,
+# per chip of the cell: a call on several chips spreads its cells over
+# them, and a fault of one chip's share shows only in its cells
 CHECK_CELLS = 1
 # the traced run holds every device op (about a million events per
 # chip-second): whole calls up to TRACE_SECONDS, never past the cap
@@ -102,7 +108,13 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     work = {w["name"]: w for w in spec["workloads"]}
     if name not in work:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(work)}")
-    w = work[name]
+    return make_cell(spec, work[name], root)
+
+
+def make_cell(spec: dict, w: dict, root: str = ROOT) -> Cell:
+    """The cell of workload entry ``w`` (``name``, ``config``,
+    ``traffic``, ``chips``) under BENCHMARK.json's ``spec``."""
+    name = w["name"]
     conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
     config = _load_json(os.path.join(root, conf["file"]))
     traffic = _load_json(
@@ -151,6 +163,15 @@ def pool_seeds(seed: int, n_calls: int, n_streams: int) -> list[list[int]]:
             for c in range(n_calls)]
 
 
+def stream_fields(cell: Cell, stream: dict, seed: int) -> dict:
+    """The fields of one stream: the configuration's ``workload``, the
+    mix, the stream's own and its generator seed (TypeError for a field
+    given twice). ``workload.kind`` names the generator
+    (``bench/gen/<kind>.py``)."""
+    return dict(**cell.config["workload"], **cell.traffic["mix"], **stream,
+                seed=seed)
+
+
 def build_pool(cell: Cell, seed: int) -> list[Call]:
     from bench import gen
 
@@ -166,11 +187,8 @@ def build_pool(cell: Cell, seed: int) -> list[Call]:
             cells.append((dict(proto, **cell.budget), si))
     pool = []
     for seeds in pool_seeds(seed, int(t["pool_calls"]), len(t["streams"])):
-        streams = [
-            gen.make_workload(gen.GenConfig(
-                **cell.config["workload"], **t["mix"], **st, seed=s))
-            for st, s in zip(t["streams"], seeds)
-        ]
+        streams = [gen.make_workload(stream_fields(cell, st, s))
+                   for st, s in zip(t["streams"], seeds)]
         pool.append(Call(
             seeds=seeds, streams=streams,
             cells=[SimCell(engine=e, stream=si, protocol=e["protocol"],
@@ -184,8 +202,7 @@ def program_workload(w, seed: int, cell: Cell, stream: dict):
     so no call reuses the arrays of another)."""
     from repro.core.workloads import Workload, WorkloadConfig
 
-    wc = WorkloadConfig(**cell.config["workload"], **cell.traffic["mix"],
-                        **stream, seed=seed)
+    wc = WorkloadConfig(**stream_fields(cell, stream, seed))
     return Workload(
         cfg=wc, keys=w.keys.copy(), modes=w.modes.copy(),
         nkeys=w.nkeys.copy(), part=w.part.copy(),
@@ -296,28 +313,37 @@ def mismatches(got: dict, want: dict) -> list[str]:
 
 def sample(calls: list[Call], seed: int, k: int) -> list[tuple[int, int]]:
     """(call, cell) pairs to check, drawn from the seed: one call, and in
-    it ``k`` of its cells."""
+    it ``k`` of its cells, one of each stream first (a group sharded over
+    chips puts its streams' cells on different chips), then the rest."""
     import numpy as np
 
     rng = np.random.default_rng([abs(int(seed)), 12])
     ci = int(rng.integers(len(calls)))
-    n = len(calls[ci].cells)
-    picked = rng.choice(n, size=min(k, n), replace=False)
-    return [(ci, int(i)) for i in sorted(picked)]
+    cells = calls[ci].cells
+    streams = sorted({c.stream for c in cells})
+    picked = [int(rng.choice([i for i, c in enumerate(cells)
+                              if c.stream == s]))
+              for s in rng.permutation(streams)[:k]]
+    rest = [i for i in range(len(cells)) if i not in picked]
+    more = min(k - len(picked), len(rest))
+    if more > 0:
+        picked += [int(i) for i in rng.choice(rest, size=more, replace=False)]
+    return [(ci, i) for i in sorted(picked)]
 
 
 def check(calls: list[Call], cell: Cell, seed: int) -> dict:
     from bench.reference import core as ref
 
     bad_total, checked = 0, []
-    for ci, i in sample(calls, seed, CHECK_CELLS):
+    for ci, i in sample(calls, seed, CHECK_CELLS * cell.chips):
         call, c = calls[ci], calls[ci].cells[i]
         t0 = time.perf_counter()
         want = ref.simulate(ref.RefConfig(**c.engine), call.streams[c.stream])
         got = fingerprint(call.results[i])
         bad = mismatches(got, want)
         bad_total += len(bad)
-        checked.append(dict(call=ci, cell=i, protocol=c.protocol,
+        checked.append(dict(call=ci, cell=i, stream=c.stream,
+                            protocol=c.protocol,
                             commits=got["commits"], mismatched=bad[:8],
                             reference_s=time.perf_counter() - t0))
         log(f"check call {ci} cell {i} ({c.protocol}): commits "
@@ -330,7 +356,8 @@ def check(calls: list[Call], cell: Cell, seed: int) -> dict:
 class TraceContext:
     """What a per-layer metric reader sees of the traced run: the trace,
     and the calls that completed inside its window (``calls``), each with
-    its span on the trace's clock."""
+    its span on the trace's clock. ``devices`` are the chips with a plane
+    in the trace, ``chips`` the number the cell holds."""
 
     def __init__(self, tr, cell: Cell, calls: list[Call], devices,
                  peaks: dict):
@@ -339,6 +366,7 @@ class TraceContext:
         self.calls = calls
         self.cells = sum(len(c.cells) for c in calls)
         self.devices = tr.device_names()[:len(devices)]
+        self.chips = len(devices)
         self.peaks = peaks
 
     def note(self, what, value) -> None:
@@ -422,7 +450,8 @@ def read_trace(tr, cell: Cell, calls: list[Call], devices, kind: str):
                 trace.in_window(tr, tr.ops(d)), short=True).items():
             ops[k] = ops.get(k, 0) + v
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
-    n_dev = max(len(ctx.devices), 1)
+    # a chip with no plane in the trace ran no operation: busy 0
+    n_dev = ctx.chips
     breakdown = {
         "device_ops": [[k, v / 1e9 / n_dev] for k, v in top],
         "idle_gaps": trace.idle_gaps(tr, ctx.devices[0]) if ctx.devices
