@@ -21,10 +21,14 @@ at a time. Each round, in order:
 7. the grant pass (``lockgrant``) grants pending requests per record in
    enqueue order; a slot re-requesting a record it write-holds is
    granted at once;
-8. deadlock handling: wait-die aborts a waiter whose holder is older
-   (one timestamp probe per new wait); wait-for and dreadlocks detect a
-   cycle of waits, one propagation step a round, and abort its youngest
-   member; their per-round costs accrue as deadlock time;
+8. deadlock handling over the waits-for relation: a waiting request
+   waits for every slot holding its record in a conflicting mode and,
+   where no holder conflicts, for every conflicting request queued ahead
+   of it (grants are FIFO); wait-die aborts a waiter that waits for an
+   older transaction (one timestamp probe per new wait); wait-for and
+   dreadlocks detect a cycle of waits, one propagation step a round, and
+   abort its youngest member; their per-round costs accrue as deadlock
+   time;
 9. shared lock tables charge each new lock-table op the record line's
    backlog plus its service time, which grows with the line's recent
    sharers when it moves between lanes (ORTHRUS is exempt);
@@ -263,18 +267,25 @@ def make_step(cfg, meta):
         kp = jnp.minimum(s["kptr"], K - 1)
         dies = jnp.zeros((T,), jnp.bool_)
         if dl != "none":
-            waiting = (s["phase"] == ACQ) & _at(s["want"] & ~s["granted"], kp)
+            pending = pend & ~grant
+            waiting = _at(pending, kp)
             wkey = jnp.where(waiting, _at(x["keys"], kp), KEY_SENTINEL)
             wwrite = _at(x["modes"], kp) == MODE_WRITE
-            # waits_on[t, u]: t waits for a record u holds in a conflicting
-            # mode
-            holds = ((x["keys"][None, :, :] == wkey[:, None, None])
-                     & s["granted"][None, :, :]
-                     & (wwrite[:, None, None]
-                        | (x["modes"] == MODE_WRITE)[None, :, :])).any(-1)
-            waits_on = (holds & waiting[:, None]
-                        & (slot[None, :] != slot[:, None])
-                        & (s["tid"] >= 0)[None, :])
+            # on[t, u, c]: u's entry c is on the record t waits for, in a
+            # mode that conflicts with t's request
+            on = ((x["keys"][None, :, :] == wkey[:, None, None])
+                  & (wwrite[:, None, None]
+                     | (x["modes"] == MODE_WRITE)[None, :, :]))
+            other = ((slot[None, :] != slot[:, None])
+                     & (s["tid"] >= 0)[None, :] & waiting[:, None])
+            holds = (on & s["granted"][None, :, :]).any(-1) & other
+            ahead = (on & pending[None, :, :] & (
+                s["enq"][None, :, :] < _at(s["enq"], kp)[:, None, None])
+            ).any(-1) & other
+            # waits_on[t, u]: u holds t's record in a conflicting mode, or,
+            # where no holder conflicts, u's conflicting request is queued
+            # ahead of t's
+            waits_on = holds | (ahead & ~holds.any(1)[:, None])
             if dl == "waitdie":
                 dies = (waits_on & (s["ts"][None, :] < s["ts"][:, None])
                         ).any(1)

@@ -11,6 +11,11 @@
   passes to the kernel (Pallas interpreter);
 * the reference equals the program on every protocol, and the control
   (the reference with isolation switched off) does not;
+* the reference's waits-for relation has FIFO queue edges: it breaks the
+  smallest deadlock closed by a read queued behind a waiting writer, and
+  commits in every chunk of a mixed read/write stream under each dynamic
+  2PL scheme (where the program, lacking that edge, differs: a strict
+  expected failure);
 * the harness run end to end at a small size is correct, and each fault
   planted under it (a runner that returns its state unchanged, half of a
   cell's transaction stream left out, a counter altered where it is
@@ -35,6 +40,7 @@ import pytest  # noqa: E402
 
 from bench import gen, run, trace  # noqa: E402
 from bench.reference import core as ref  # noqa: E402
+from bench.reference.data import MODE_READ, MODE_WRITE, Workload  # noqa: E402
 
 SMALL_BUDGET = dict(max_rounds=600, warmup_rounds=200, chunk_rounds=200,
                     target_commits=100_000_000)
@@ -385,6 +391,107 @@ def test_reference_matches_program_and_control_does_not(wkw, ekw):
     control = ref.simulate(
         ref.RefConfig(**ekw, **SMALL_BUDGET, isolation=False), w)
     assert run.mismatches(control, want) != []
+
+
+# The waits-for relation under FIFO grants: a read queued behind a
+# waiting writer waits for that writer, though every holder of its
+# record reads.
+def fifo_deadlock():
+    """The smallest FIFO deadlock. Admitted in slot order, so the
+    timestamps run W < H < R: H read-holds x; W's write on x waits for H;
+    R, which read-holds y, queues a read on x behind W; H's write on y
+    waits for R. Only R's edge to the queued W closes W -> H -> R -> W,
+    and no edge to a holder points at an older transaction."""
+    rd, wr = MODE_READ, MODE_WRITE
+    x, y, z = 1, 2, 0
+    keys = np.array([[z, x], [x, y], [y, x]], np.int32)  # W, H, R
+    modes = np.array([[wr, wr], [rd, wr], [rd, rd]], np.int32)
+    n = len(keys)
+    return Workload(keys=keys, modes=modes, nkeys=np.full(n, 2, np.int32),
+                    part=keys % 16, exec_ops=np.full(n, 2, np.int32),
+                    ollp=np.zeros(n, bool), ollp_miss=np.zeros(n, bool),
+                    num_records=16, batch_epoch=512)
+
+
+DYNAMIC_2PL = ["twopl_waitdie", "twopl_waitfor", "twopl_dreadlocks"]
+
+
+@pytest.mark.parametrize("protocol", DYNAMIC_2PL)
+def test_fifo_deadlock_aborts_the_read_behind_a_writer(protocol):
+    w = fifo_deadlock()
+    cfg = ref.RefConfig(protocol=protocol, n_exec=3, **SMALL_BUDGET)
+    loop = ref._LockLoop(cfg, ref.make_plan(cfg, w))
+    for r in range(1, 65):
+        loop.run_to(r)
+        if int(loop.state["aborts_dl"]):
+            break
+    assert int(loop.state["aborts_dl"]) == 1, "the deadlock is never broken"
+    # R (slot 2): wait-die on its edge to the older W; wait-for and
+    # dreadlocks as the youngest member of the cycle
+    phase = np.asarray(loop.state["phase"])
+    aborted = np.isin(phase, (ref.REL, ref.BACKOFF)) & ~np.asarray(
+        loop.state["committing"])
+    assert aborted.tolist() == [False, False, True]
+    assert ref.simulate(cfg, w)["commits"] > 0
+
+
+# the mixed-mode stream: 48 hot records, each txn reading the keys of its
+# own parity and writing the rest, at 40 lanes; a budget in which
+# wait-for and dreadlocks without the queue edge stop committing
+MIXED = dict(kind="ycsb", num_txns=512, num_records=20_000, num_hot=48,
+             seed=0)
+MIXED_BUDGET = dict(max_rounds=8000, warmup_rounds=1000, chunk_rounds=1000,
+                    target_commits=100_000_000)
+
+
+def mixed_modes(w):
+    parity = (w.keys + np.arange(len(w.keys))[:, None]) % 2
+    return dataclasses.replace(w, modes=np.where(
+        parity == 0, MODE_READ, MODE_WRITE).astype(w.modes.dtype))
+
+
+@pytest.fixture(scope="module")
+def mixed_stream():
+    return mixed_modes(gen.make_workload(MIXED))
+
+
+@pytest.mark.parametrize("protocol", DYNAMIC_2PL)
+def test_reference_commits_on_mixed_modes(mixed_stream, protocol):
+    cfg = ref.RefConfig(protocol=protocol, n_exec=40, **MIXED_BUDGET)
+    loop = ref._LockLoop(cfg, ref.make_plan(cfg, mixed_stream))
+    commits = [0]
+    for b in ref.chunk_boundaries(cfg):
+        loop.run_to(b)
+        commits.append(int(loop.state["commits"]))
+    assert np.all(np.diff(commits) > 0), commits
+    want = ref.simulate(cfg, mixed_stream)
+    assert want["aborts_deadlock"] > 0
+    control = ref.simulate(dataclasses.replace(cfg, isolation=False),
+                           mixed_stream)
+    assert run.mismatches(control, want) != []
+
+
+PROGRAM_LACKS_QUEUE_EDGE = (
+    "the program's waits-for relation (src/repro/core/engine.py:1527-1557, "
+    "adj in stage.deadlock) links a waiter only to conflicting holders: a "
+    "read queued behind a waiting writer has no edge, so the cycle it "
+    "closes is never broken")
+
+
+@pytest.mark.parametrize("protocol", [
+    pytest.param(p, marks=pytest.mark.xfail(
+        strict=True, raises=AssertionError, reason=PROGRAM_LACKS_QUEUE_EDGE))
+    for p in DYNAMIC_2PL])
+def test_reference_matches_program_on_mixed_modes(mixed_stream, protocol):
+    from repro.core import sweep
+    from repro.core.engine import EngineConfig
+    from repro.core.workloads import WorkloadConfig, make_workload
+
+    wl = mixed_modes(make_workload(WorkloadConfig(**MIXED)))
+    ekw = dict(protocol=protocol, n_exec=40, **MIXED_BUDGET)
+    res = sweep.run_cells([(EngineConfig(**ekw), wl)])[0]
+    want = ref.simulate(ref.RefConfig(**ekw), mixed_stream)
+    assert run.mismatches(run.fingerprint(res), want) == []
 
 
 # ------------------------------------------- the harness, end to end
