@@ -100,9 +100,10 @@ from repro.core.lockgrant import (
     REQ_READ,
     REQ_RELEASE,
     REQ_WRITE,
-    inverse_permutation,
     lex_order,
     segmented_grant,
+    sort_requests,
+    unsort,
 )
 from repro.core.metrics import LAT_BUCKETS, QDEPTH_SAMPLES
 from repro.core.workloads import MODE_READ, MODE_WRITE, Workload
@@ -1028,7 +1029,7 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
     rdr_slots = [slice(32 * w, 32 * (w + 1)) for w in range((T + 31) // 32)]
     use_pallas = cfg.is_orthrus and _use_pallas(cfg)
     if use_pallas:
-        from repro.kernels.lock_grant.ops import lock_grant as _lock_grant
+        from repro.kernels.lock_grant.ops import grant_sorted as _grant_sorted
         from repro.kernels.segscan import TILE
 
         # one grid step up to 8 (8, 128) tiles (the op rounds the block
@@ -1362,44 +1363,36 @@ def make_step(cfg: EngineConfig, meta: PlanMeta):
                     (pend2d | rel_entries), keys, KEY_SENTINEL
                 ).reshape(-1)
                 ent_enq = s["enq"].reshape(-1)
-                safe = jnp.minimum(ent_key, R - 1)
-                in_rng = ent_key < R
                 with jax.named_scope("kernel.lock_grant"):
+                    # one sort by (key, enq) carrying kind and entry
+                    # index; the lock table read at the sorted keys
+                    e = sort_requests(
+                        ent_key, ent_enq, ent_kind, s["wh"], s["rc"], R
+                    )
                     if use_pallas:
                         # the Pallas segmented-grant kernel (compiled on
                         # TPU/GPU, interpreted elsewhere — see
                         # kernels.resolve_interpret); bit-identical to the jnp
                         # path below, which is its oracle
-                        g_flat, _cont = _lock_grant(
-                            ent_key, ent_enq, ent_kind, s["wh"], s["rc"],
-                            num_records=R, block_n=grant_block,
+                        g_sorted, _op_pos = _grant_sorted(
+                            e.keys, e.kind, e.wh_free, e.rc,
+                            block_n=grant_block,
                         )
-                        grant = g_flat.reshape(T, K)
                     else:
-                        wh_free = (s["wh"][safe] == -1) & in_rng
-                        rcv = jnp.where(in_rng, s["rc"][safe], 0)
-                        order = lex_order(ent_key, ent_enq)
-                        inv = inverse_permutation(order)
                         g_sorted, _cont, _new = segmented_grant(
-                            ent_key[order],
-                            ent_enq[order],
-                            ent_kind[order],
-                            wh_free[order],
-                            rcv[order],
+                            e.keys, e.ts, e.kind, e.wh_free, e.rc
                         )
-                        grant = g_sorted[inv].reshape(T, K)
                 # re-entrant grants bypass the FIFO: a slot re-requesting a key
                 # it already write-holds is granted immediately (real
                 # transactions touch the same row more than once; without this
-                # they would deadlock on their own lock)
-                ent_slot = jnp.broadcast_to(slot_ids[:, None], (T, K)).reshape(-1)
+                # they would deadlock on their own lock). Entry i is slot
+                # i // K's column i % K.
                 self_grant = (
-                    (ent_kind != REQ_NONE)
-                    & (ent_kind != REQ_RELEASE)
-                    & in_rng
-                    & (s["wh"][safe] == ent_slot)
+                    ((e.kind == REQ_READ) | (e.kind == REQ_WRITE))
+                    & e.in_range
+                    & (e.holder == e.index // K)
                 )
-                grant = grant | self_grant.reshape(T, K)
+                grant = unsort(g_sorted | self_grant, e.index).reshape(T, K)
 
                 # apply grants to the lock table
                 gk = jnp.where(grant, keys, 0)

@@ -15,8 +15,11 @@ model for shared-memory lock tables.
 
 ``segmented_grant`` operates on **pre-sorted** request arrays and is the
 contract implemented by the Pallas kernel in ``repro.kernels.lock_grant``
-(this jnp version is its oracle). ``grant_round`` is the engine-facing
-wrapper that sorts / unsorts.
+(this jnp version is its oracle). ``sort_requests`` and ``unsort`` take a
+round's requests into (key, ts) order and back, with one sort each way;
+the engine's ORTHRUS grant pass and the kernel's wrapper share them.
+``grant_round`` is the plain sort / decide / unsort by gathers, kept as
+the oracle of both.
 
 Entry types: ``REQ_READ`` / ``REQ_WRITE`` are grantable requests;
 ``REQ_RELEASE`` entries participate in contender counting only (a release is
@@ -27,6 +30,8 @@ sorting by (key, ts) uses two stable argsorts instead of a packed composite.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -48,11 +53,64 @@ def lex_order(primary, secondary):
     keep original order) at roughly half the cost — sorts are the hottest
     ops in the engine's round loop.
     """
+    return lex_sort(primary, secondary)[-1]
+
+
+def lex_sort(primary, secondary, *payloads):
+    """``lex_order``'s stable sort by (primary, secondary), carrying
+    ``payloads`` and the entry index ``iota`` through it.
+
+    Returns ``(primary, secondary, *payloads, index)`` in sorted order,
+    where ``index`` is ``lex_order(primary, secondary)`` itself: the
+    sort delivers its operands sorted, with no gather by the order.
+    """
     iota = jnp.arange(primary.shape[0], dtype=jnp.int32)
-    _, _, order = jax.lax.sort(
-        (primary, secondary, iota), dimension=-1, num_keys=2, is_stable=True
+    return jax.lax.sort(
+        (primary, secondary, *payloads, iota), dimension=-1,
+        num_keys=2, is_stable=True,
     )
-    return order
+
+
+class SortedRequests(NamedTuple):
+    """Grant-pass entries in (key, ts) order, with the lock table read at
+    their keys (``sort_requests``)."""
+
+    keys: jax.Array  # int32[N] sorted keys
+    ts: jax.Array  # int32[N] enqueue stamps
+    kind: jax.Array  # int32[N] REQ_* kinds
+    index: jax.Array  # int32[N] entry index in request order
+    in_range: jax.Array  # bool[N] key < num_records
+    holder: jax.Array  # int32[N] the key's write holder (-1: none)
+    wh_free: jax.Array  # bool[N] in range and no write holder
+    rc: jax.Array  # int32[N] the key's read count (0 out of range)
+
+
+def sort_requests(keys, ts, kind, write_holder, read_count, num_records):
+    """Sort requests once by (key, ts), carrying kind and entry index, and
+    read the ``[num_records]`` lock-table columns at the sorted keys.
+
+    Everything the grant pass needs comes out in sorted order: one sort
+    and the two lock-table gathers, no permutation gathers. ``unsort``
+    takes a sorted result back to request order.
+    """
+    ks, ts_s, kind_s, index = lex_sort(keys, ts, kind)
+    safe = jnp.minimum(ks, num_records - 1)
+    in_range = ks < num_records
+    holder = write_holder[safe]
+    return SortedRequests(
+        keys=ks, ts=ts_s, kind=kind_s, index=index, in_range=in_range,
+        holder=holder, wh_free=(holder == -1) & in_range,
+        rc=jnp.where(in_range, read_count[safe], 0),
+    )
+
+
+def unsort(x_sorted, index):
+    """Put values in ``sort_requests`` order back into request order:
+    ``out[index[i]] = x_sorted[i]``, one sort keyed by the entry index
+    (a permutation: no ties, so the sort need not be stable)."""
+    return jax.lax.sort(
+        (index, x_sorted), dimension=-1, num_keys=1, is_stable=False
+    )[1]
 
 
 def inverse_permutation(order):

@@ -3,10 +3,11 @@
 The interpret-mode kernel tests cannot see what the chip's compiler
 refuses (``cumsum``, single-element extraction, slices off the
 ``(8, 128)`` tiling). These tests lower the ``lock_grant`` and
-``dep_wavefront`` kernels, and the ``twopl_waitdie``, ``twopl_dreadlocks``
-and ``dgcc`` chunk runners, for one chip of a described ``v5e:2x2``
-topology and compile them with the installed TPU compiler, and check
-the compiled lock-table loop for per-record copies and row scatters.
+``dep_wavefront`` kernels, and the ``twopl_waitdie``, ``twopl_dreadlocks``,
+``dgcc`` and ``orthrus`` chunk runners, for one chip of a described
+``v5e:2x2`` topology and compile them with the installed TPU compiler,
+and check the compiled lock-table loop for per-record copies and row
+scatters, and ORTHRUS's grant pass for gathers and sorts by scope.
 Nothing runs.
 
 The topology is described inside a module-scoped fixture, never at
@@ -111,12 +112,12 @@ _SIM = dict(max_rounds=6000, warmup_rounds=2000, chunk_rounds=2000,
             target_commits=100_000_000)
 
 
-def _compile_runner(eng, one_chip, cells=0):
-    """Compile the chunk runner of ``eng`` on ``_YCSB`` for one described
-    chip: one cell, or a vmapped group of ``cells``. Returns the compiled
-    runner and the plan's shapes."""
+def _compile_runner(eng, one_chip, cells=0, wl=_YCSB):
+    """Compile the chunk runner of ``eng`` on the stream ``wl`` (``_YCSB``
+    unless given) for one described chip: one cell, or a vmapped group of
+    ``cells``. Returns the compiled runner and the plan's shapes."""
     cfg = EngineConfig(**eng, **_SIM)
-    plan = engine_lib.make_plan(cfg, make_workload(WorkloadConfig(**_YCSB)))
+    plan = engine_lib.make_plan(cfg, make_workload(WorkloadConfig(**wl)))
     meta = engine_lib.plan_meta(cfg, plan)
     p = engine_lib.plan_device(cfg, plan)
     if cfg.is_batch_planned:
@@ -149,17 +150,21 @@ def test_chunk_runner_compiles(eng, kernels, one_chip, no_persistent_cache,
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
-_OP = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+# an instruction's shape (the first of a tuple's), opcode and op_name
+_OP = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[([\d,]*)\]\S*(?:, \S+)*"
+                 r" ([\w\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLED = re.compile(
     r"(?:calls|to_apply|body|condition)=%([\w.\-]+)"
     r"|branch_computations=\{([^}]*)\}"
 )
 
 
-def _ops(text: str, loop_only: bool) -> list[tuple[str, tuple[int, ...]]]:
-    """``(opcode, dims)`` of the array-shaped instructions of a compiled
-    module's text: all of them, or only those a ``while`` body runs
-    (the body and every computation it calls, fusions included)."""
+def _ops(text: str, loop_only: bool) -> list[tuple[str, tuple[int, ...], str]]:
+    """``(opcode, dims, op_name)`` of the array- and tuple-shaped
+    instructions of a compiled module's text (``dims`` of a tuple's first
+    element): all of them, or only those a ``while`` body runs (the body
+    and every computation it calls, fusions included)."""
     comps, cur = {}, None
     for line in text.splitlines():
         m = _COMPUTATION.match(line)
@@ -187,7 +192,8 @@ def _ops(text: str, loop_only: bool) -> list[tuple[str, tuple[int, ...]]]:
             m = _OP.match(line)
             if m:
                 dims = tuple(int(d) for d in m.group(1).split(",") if d)
-                ops.append((m.group(2), dims))
+                name = _OP_NAME.search(line)
+                ops.append((m.group(2), dims, name.group(1) if name else ""))
     return ops
 
 
@@ -211,8 +217,8 @@ def test_record_columns_not_copied(eng, cells, one_chip, no_persistent_cache):
     text = compiled.as_text()
     R = meta.num_records
     loop = _ops(text, loop_only=True)
-    assert any(op == "scatter" and R in dims for op, dims in loop)
-    for op, dims in loop:
+    assert any(op == "scatter" and R in dims for op, dims, _ in loop)
+    for op, dims, _ in loop:
         if op in ("copy", "scatter") and R in dims:
             assert dims.index(R) == len(dims) - 1, (op, dims)
     if cells:
@@ -220,6 +226,36 @@ def test_record_columns_not_copied(eng, cells, one_chip, no_persistent_cache):
         # each round; on a TPU v5e that measured faster than a loop
         # written out to skip the select for them
         return
-    copied = [dims for op, dims in _ops(text, loop_only=False)
+    copied = [dims for op, dims, _ in _ops(text, loop_only=False)
               if op == "copy" and R in dims]
     assert not copied, copied
+
+
+# A TPC-C stream no other test compiles, on 50 stock records a warehouse
+_TPCC = dict(kind="tpcc", num_txns=512, num_warehouses=4, stock_per_wh=50,
+             seed=0)
+
+
+def test_orthrus_grant_pass_gathers(one_chip, no_persistent_cache,
+                                    monkeypatch):
+    """ORTHRUS's grant pass sorts its T * K entries once, carrying their
+    kind and index, reads the lock table at the sorted keys and puts the
+    grants back in slot order once. So the round loop's ``stage.grant``
+    gathers T * K entries at most twice, for the two lock-table columns,
+    and sorts at most twice. On the chip a gather of a few thousand
+    scalars costs several microseconds; moving the entries into sorted
+    order and back by gathers took five more of them a round."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    eng = dict(protocol="orthrus", n_cc=4, n_exec=16, window=4,
+               kernel_impl="pallas")
+    compiled, meta = _compile_runner(eng, one_chip, wl=_TPCC)
+    assert _kernel_count(compiled) == 1
+    n = 16 * 4 * meta.max_keys
+    grant = [(op, dims) for op, dims, name in _ops(compiled.as_text(),
+                                                   loop_only=True)
+             if "/stage.grant/" in name]
+    gathers = [dims for op, dims in grant
+               if op == "gather" and dims and dims[0] >= n]
+    sorts = [dims for op, dims in grant if op == "sort"]
+    assert 0 < len(gathers) <= 2, gathers
+    assert 0 < len(sorts) <= 2, sorts
